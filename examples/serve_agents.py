@@ -90,11 +90,13 @@ from repro.api import (
 )
 from repro.api.workload import DEFAULT_CLASSES
 from repro.core import scheduler_names
+from repro.launch.compile_cache import enable_compile_cache
 from repro.predictor import AgentCostPredictor
 from repro.workloads import sample_agent
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--backend", default="engine",
                     choices=("engine", "sim"))

@@ -57,6 +57,7 @@ from repro.api.workload import (
     service_for_backend,
     specs_from_classes,
     specs_from_closed_loop,
+    warmup_engines,
 )
 
 __all__ = [
@@ -97,4 +98,5 @@ __all__ = [
     "specs_from_classes",
     "specs_from_closed_loop",
     "service_for_backend",
+    "warmup_engines",
 ]

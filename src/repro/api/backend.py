@@ -36,6 +36,7 @@ from repro.core import make_scheduler
 from repro.core.cost import InferenceSpec, MemoryFamily, agent_cost
 from repro.core.schedulers import AgentScheduler
 from repro.engine import EngineAgent, ServeEngine
+from repro.engine.engine import prompt_bucket
 from repro.sim import ClusterSim, SimAgent
 
 
@@ -441,8 +442,9 @@ class EngineBackend:
         """
         d = max(1, int(round(s.decode / self.token_scale)))
         if prompt is None:
-            p = max(1, int(round(s.prefill / self.token_scale)))
-            prompt = self._rng.integers(0, self._vocab, size=p)
+            prompt = self._rng.integers(
+                0, self._vocab, size=self._prompt_len(s)
+            )
         else:
             prompt = np.asarray(prompt)
         return prompt, d
@@ -463,8 +465,27 @@ class EngineBackend:
         sessions' engine prompts identical.  The stream must be at
         least ``prefill`` ids long (the sessions guarantee it).
         """
-        p = max(1, int(round(s.prefill / self.token_scale)))
+        p = self._prompt_len(s)
         return np.asarray(ids)[:: self.token_scale][:p] % self._vocab
+
+    def _prompt_len(self, s: InferenceSpec) -> int:
+        """Engine-scale prompt length of one full-scale spec."""
+        return max(1, int(round(s.prefill / self.token_scale)))
+
+    def warmup(self, specs: Sequence[AgentSpec]) -> None:
+        """Pre-compile the engine's programs for every prompt bucket the
+        opening stages of ``specs`` prefill at (``ServeEngine.warmup``);
+        stages a closed-loop session appends later compile on first use."""
+        buckets = {
+            prompt_bucket(
+                len(spec.prompts[i][j]) if spec.prompts is not None
+                else self._prompt_len(s)
+            )
+            for spec in specs
+            for i, stage in enumerate(spec.stages)
+            for j, s in enumerate(stage)
+        }
+        self.engine.warmup(tuple(sorted(buckets)))
 
     def _stage_prompt(
         self, spec: AgentSpec, i: int, j: int, s: InferenceSpec

@@ -469,21 +469,36 @@ class AgentService:
     ) -> "AgentService":
         """Service over the real JAX continuous-batching engine.
 
-        ``replicas > 1`` builds N engines (sharing ``model``/``params`` but
-        each with its own KV pool, batch slots, and scheduler) behind a
+        ``replicas > 1`` builds N engines (sharing ``model`` but each with
+        its own KV pool, batch slots, and scheduler) behind a
         :class:`ReplicatedBackend`; replica k synthesizes prompts from
-        ``seed + k`` so fleets are deterministic but decorrelated.
-        Fleet-level fault-tolerance kwargs (``fault_plan`` / ``watchdog_*``)
-        go to the :class:`ReplicatedBackend`, the rest to the children.
+        ``seed + k`` so fleets are deterministic but decorrelated, and is
+        served from ``jax.devices()[k % len(jax.devices())]``: its params
+        (copied there once per device), cache and slot state live on that
+        device.  Fleet-level fault-tolerance kwargs (``fault_plan`` /
+        ``watchdog_*``) go to the :class:`ReplicatedBackend`, the rest to
+        the children.
         """
+        import jax
+
         from repro.api.backend import EngineBackend
 
         fleet_kw = {k: kw.pop(k) for k in cls._FLEET_KW if k in kw}
         counter = iter(range(replicas if replicas > 1 else 1))
+        devices = jax.devices()
+        placed = {}     # device -> params there (aliased, not copied,
+                        # on the device that already holds them)
 
         def make():
+            k = next(counter)
+            dev_params = params
+            if replicas > 1:
+                dev = devices[k % len(devices)]
+                if dev not in placed:
+                    placed[dev] = jax.device_put(params, dev)
+                dev_params = placed[dev]
             return EngineBackend(
-                model, params, scheduler, seed=seed + next(counter), **kw
+                model, dev_params, scheduler, seed=seed + k, **kw
             )
 
         return cls._maybe_replicated(

@@ -13,7 +13,7 @@ from typing import Optional, Sequence
 
 import numpy as np
 
-from repro.api.backend import AgentSpec
+from repro.api.backend import AgentSpec, EngineBackend
 from repro.api.service import AgentService
 from repro.workloads import (
     CLOSED_LOOP_CLASSES,
@@ -37,6 +37,12 @@ DEFAULT_CLOSED_LOOP = tuple(
 #: engine serves token demands divided by this (predicted costs by its
 #: square, since KV token-time is ~quadratic in token counts)
 DEFAULT_TOKEN_SCALE = 8
+
+#: engine sizes for granite-3-2b at published widths on one 16 GB TPU v5e:
+#: with prompts of at most one 512-token prefill chunk, every program the
+#: engine compiles fits the 15.75 GiB the chip's compiler allows
+#: (``tests/test_tpu_compile.py`` pins this)
+V5E_ENGINE_KW = {"max_batch": 8, "cache_len": 2048, "pool_tokens": 16384}
 
 
 def specs_from_classes(
@@ -127,6 +133,7 @@ def service_for_backend(
     scheduler: str,
     *,
     arch: str = "granite-3-2b",
+    reduced: bool = True,
     vocab: int = 512,
     pool_tokens: int = 4096,
     max_batch: int = 4,
@@ -152,6 +159,11 @@ def service_for_backend(
     steal_interval: Optional[float] = None,
 ) -> AgentService:
     """Build an AgentService for ``backend`` in {"sim", "engine"}.
+
+    The engine serves ``arch``'s smoke-test variant (``.reduced(vocab=
+    vocab)``: 2 layers, float32) by default; ``reduced=False`` serves
+    ``get_config(arch)`` unchanged — published widths and dtype, with
+    ``vocab`` ignored.
 
     The sim pool is ``pool_tokens * sim_kv_factor`` KV units: the simulator
     serves full-scale token demands while the engine serves them divided by
@@ -239,7 +251,9 @@ def service_for_backend(
     from repro.configs import get_config
     from repro.models import Model
 
-    cfg = get_config(arch).reduced(vocab=vocab)
+    cfg = get_config(arch)
+    if reduced:
+        cfg = cfg.reduced(vocab=vocab)
     model = Model(cfg)
     params = model.init(jax.random.PRNGKey(seed))
     return AgentService.engine(
@@ -252,3 +266,13 @@ def service_for_backend(
         **child_kw,
         **fleet_kw,
     )
+
+
+def warmup_engines(service: AgentService, specs: Sequence[AgentSpec]) -> None:
+    """Pre-compile every engine behind ``service`` (each replica of a
+    fleet) for the prompt buckets ``specs`` prefill at, so serving does not
+    stall on the compiler; a no-op for the sim."""
+    backend = service.backend
+    for child in getattr(backend, "children", (backend,)):
+        if isinstance(child, EngineBackend):
+            child.warmup(specs)

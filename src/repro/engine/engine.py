@@ -240,6 +240,13 @@ def _scatter_slot_jit(cache, small, slot):
     )
 
 
+def prompt_bucket(p: int) -> int:
+    """The padded length a ``p``-token prompt prefills at on the batched
+    path: the next multiple of 64, so each bucket compiles one program per
+    power-of-two batch pad (``ServeEngine.warmup`` takes these)."""
+    return -(-max(p, 1) // 64) * 64
+
+
 @dataclasses.dataclass
 class EngineRequest:
     """One inference task: prompt tokens + a decode budget."""
@@ -456,8 +463,14 @@ class ServeEngine:
         self.cache_len = cache_len
         self.prefill_chunk = prefill_chunk
         self.max_window = max(1, int(max_window))
+        #: the one device holding ``params``: the cache, the slot state and
+        #: every upload are placed there, so a fleet that gives replica k
+        #: its own copy of the params on chip k serves it from chip k
+        (self.device,) = jax.tree.leaves(params)[0].devices()
 
-        self.cache = model.init_cache(params, max_batch, cache_len)
+        with jax.default_device(self.device):
+            self.cache = model.init_cache(params, max_batch, cache_len)
+            self._d_state = jnp.zeros((3, max_batch), jnp.int32)
         self.slot_free = list(range(max_batch))
         self.slot_req: dict[int, EngineRequest] = {}
         # host mirrors of the device-resident slot tensors: authoritative
@@ -465,7 +478,6 @@ class ServeEngine:
         # source for rebuilding the device copies when occupancy changes
         self.slot_last_tok = np.zeros(max_batch, np.int32)
         self.slot_pos = np.zeros(max_batch, np.int32)
-        self._d_state = jnp.zeros((3, max_batch), jnp.int32)
         self._slots_stale = True   # device copy needs a rebuild
 
         # waiting/swapped/running share the OrderedQueue (repro.core.
@@ -535,6 +547,10 @@ class ServeEngine:
         """
         if self.slot_req or self.busy:
             raise RuntimeError("warmup must run on an idle engine")
+        with jax.default_device(self.device):
+            self._warmup(prompt_buckets)
+
+    def _warmup(self, prompt_buckets: tuple[int, ...]) -> None:
         k = 1
         while k <= self.max_window:
             self.cache, self._d_state, toks = _decode_window_jit(
@@ -791,16 +807,17 @@ class ServeEngine:
             raise RuntimeError("re-entrant step() from a listener callback")
         self._in_step = True
         try:
-            start = self.now
-            self._release_arrivals()
-            self._release_resumes()
-            self._admit()
-            if limit is not None:
-                # the admission pass may itself advance the clock (chunked
-                # prefill cost); shrink the decode budget so a fused window
-                # never runs past the caller's `until` horizon
-                limit = max(1, int(limit) - (self.now - start))
-            k = self._decode_once(limit)
+            with jax.default_device(self.device):
+                start = self.now
+                self._release_arrivals()
+                self._release_resumes()
+                self._admit()
+                if limit is not None:
+                    # the admission pass may itself advance the clock
+                    # (chunked prefill cost); shrink the decode budget so a
+                    # fused window never runs past the caller's `until`
+                    limit = max(1, int(limit) - (self.now - start))
+                k = self._decode_once(limit)
             self.now += 1
             return k
         finally:
@@ -1043,7 +1060,7 @@ class ServeEngine:
                            fetch_tok: bool):
         """Write the first ``n`` prompt tokens' K/V into the request's slot
         via the batched prefill program (single row, 64-token bucket)."""
-        bucket = -(-max(n, 1) // 64) * 64
+        bucket = prompt_bucket(n)
         toks = np.zeros((1, bucket), np.int32)
         toks[0, :n] = req.prompt[:n]
         self.cache, nxt = _prefill_write_jit(
@@ -1108,7 +1125,7 @@ class ServeEngine:
                 # a power of two: each bucket compiles O(log max_batch)
                 # prefill programs, padding rows cost only a little wasted
                 # compute
-                bucket = max(-(-max(p, 1) // 64) * 64 for p in plens)
+                bucket = max(prompt_bucket(p) for p in plens)
                 k_pad = 1 << (k - 1).bit_length() if k > 1 else 1
             else:
                 bucket = max(max(p, 1) for p in plens)

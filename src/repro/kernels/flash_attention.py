@@ -108,7 +108,7 @@ def flash_attention(
     window: int = 0,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     b, nh, s, hd = q.shape
     n_kv = k.shape[1]

@@ -112,7 +112,7 @@ def mlstm_chunk_kernel(
     log_f,   # (B, H, S)
     *,
     chunk: int = 64,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     b, h, s, hd = q.shape
     if s % chunk:

@@ -1,21 +1,16 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-``interpret`` defaults to True when no TPU is present (this container), so
-the same call sites run the kernel body in interpret mode on CPU and compile
-to Mosaic on a real TPU.
+``interpret`` defaults to False: the kernels compile to Mosaic for the
+TPU.  Callers on a backend without Mosaic (the CPU tests) pass
+``interpret=True`` explicitly.
 """
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.kernels.flash_attention import flash_attention as _flash
 from repro.kernels.paged_attention import paged_attention as _paged
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def paged_gqa_decode(
@@ -26,7 +21,7 @@ def paged_gqa_decode(
     lengths,        # (B,) int32
     *,
     block_size: int = 16,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ):
     """Paged decode attention; returns (B, nh, hd)."""
     b, nh, hd = q.shape
@@ -36,7 +31,7 @@ def paged_gqa_decode(
     out = _paged(
         qg, k_pages, v_pages, block_tables, lengths,
         block_size=block_size,
-        interpret=_default_interpret() if interpret is None else interpret,
+        interpret=interpret,
     )
     return out.reshape(b, nh, hd)
 
@@ -49,7 +44,7 @@ def flash_prefill(
     window: int = 0,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ):
     """Causal (optionally SWA) prefill attention; returns (B, S, nh, hd)."""
     hd = q.shape[-1]
@@ -59,6 +54,6 @@ def flash_prefill(
     out = _flash(
         qt, kt, vt,
         window=window, block_q=block_q, block_k=block_k,
-        interpret=_default_interpret() if interpret is None else interpret,
+        interpret=interpret,
     )
     return jnp.swapaxes(out, 1, 2)

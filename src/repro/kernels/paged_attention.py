@@ -101,7 +101,7 @@ def paged_attention(
     lengths,        # (B,) int32
     *,
     block_size: int = 16,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     b, n_kv, qpk, hd = q.shape
     max_pages = block_tables.shape[1]

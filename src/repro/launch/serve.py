@@ -19,10 +19,14 @@ not upfront.
 clocks, reconciled global virtual time); ``--router`` picks the placement
 policy from the router registry (``repro.api.router_names()``).
 
-CPU runs the reduced model variant end-to-end; the full configs are
-validated against the production mesh by the dry-run (repro.launch.dryrun),
-which this launcher shares all sharding policy with.  Installed as the
-``repro-serve`` console entrypoint (see pyproject.toml).
+The engine serves the reduced model variant (2 layers, float32) by
+default, which is what runs on a CPU; ``--no-reduced`` serves the arch's
+published widths and dtype (granite-3-2b fits one 16 GB TPU v5e at
+``--max-batch 8 --cache-len 2048 --pool-tokens 16384 --token-scale 1``
+with prompts of at most 512 tokens).  The engine is warmed
+up for the workload's prompt buckets before serving, and compiled programs
+go to the persistent cache (``repro.launch.compile_cache``).  Installed as
+the ``repro-serve`` console entrypoint (see pyproject.toml).
 """
 
 from __future__ import annotations
@@ -32,12 +36,20 @@ import time
 
 import numpy as np
 
-from repro.api import router_names, service_for_backend, specs_from_classes
+from repro.api import (
+    router_names,
+    service_for_backend,
+    specs_from_classes,
+    warmup_engines,
+)
+from repro.api.workload import DEFAULT_TOKEN_SCALE
 from repro.configs import ALL_ARCHS
 from repro.core import scheduler_names
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-3-2b", choices=ALL_ARCHS)
     ap.add_argument("--backend", default="engine", choices=("engine", "sim"))
@@ -46,6 +58,14 @@ def main() -> None:
     ap.add_argument("--n-agents", type=int, default=6)
     ap.add_argument("--pool-tokens", type=int, default=4096)
     ap.add_argument("--max-batch", type=int, default=4)
+    ap.add_argument("--cache-len", type=int, default=512,
+                    help="(engine) KV rows per batch slot")
+    ap.add_argument("--token-scale", type=int, default=DEFAULT_TOKEN_SCALE,
+                    help="(engine) serve token demands divided by this")
+    ap.add_argument("--reduced", action="store_true", default=True,
+                    help="(engine) serve the reduced model (default)")
+    ap.add_argument("--no-reduced", dest="reduced", action="store_false",
+                    help="(engine) serve the arch at published widths")
     ap.add_argument("--window-s", type=float, default=20.0,
                     help="arrival window (workload seconds)")
     ap.add_argument("--replicas", type=int, default=1,
@@ -100,8 +120,9 @@ def main() -> None:
     specs = specs_from_classes(rng, args.n_agents, args.window_s)
     service = service_for_backend(
         args.backend, args.scheduler,
-        arch=args.arch, pool_tokens=args.pool_tokens,
-        max_batch=args.max_batch,
+        arch=args.arch, reduced=args.reduced, pool_tokens=args.pool_tokens,
+        max_batch=args.max_batch, cache_len=args.cache_len,
+        token_scale=args.token_scale,
         replicas=args.replicas, router=args.router,
         watchdog_timeout=args.watchdog_timeout,
         watchdog_retries=args.watchdog_retries,
@@ -115,6 +136,11 @@ def main() -> None:
         steal_threshold=args.steal_threshold,
         steal_interval=args.steal_interval,
     )
+
+    if args.backend == "engine":
+        t0 = time.time()
+        warmup_engines(service, specs)
+        print(f"warmup={time.time() - t0:.1f}s")
 
     t0 = time.time()
     service.submit_many(specs)

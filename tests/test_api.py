@@ -528,3 +528,33 @@ def test_run_until_idle_stall_carries_diagnostics(tiny_model):
         assert fragment in str(err)
     assert err.completions == {}
     assert err.metrics["tokens"] > 0          # partial progress surfaced
+
+
+@pytest.mark.parametrize("env_dir", [None, "elsewhere/jax-cache"])
+def test_compile_cache_placement(env_dir):
+    """``enable_compile_cache`` leaves ``$JAX_COMPILATION_CACHE_DIR`` to
+    JAX when it is set and otherwise points JAX at ``<repo>/.jax_cache``
+    (run in a child so this process never turns the cache on)."""
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH="src")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if env_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+    code = (
+        "import jax\n"
+        "from repro.launch.compile_cache import enable_compile_cache\n"
+        "print(enable_compile_cache())\n"
+        "print(jax.config.jax_compilation_cache_dir)\n"
+    )
+    proc = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        env=env, cwd=root, timeout=120,
+    )
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    returned, configured = proc.stdout.split()
+    want = env_dir or os.path.join(root, ".jax_cache")
+    assert returned == configured == want

@@ -13,6 +13,12 @@ capacity.
 
 from __future__ import annotations
 
+import json
+import os
+import subprocess
+import sys
+import textwrap
+
 import numpy as np
 import pytest
 from hypothesis import given, settings
@@ -394,3 +400,84 @@ def test_engine_fleet_concurrent_bit_identical(tiny_model):
     assert con.jct == seq.jct
     assert con.event_counts == seq.event_counts
     assert con.metrics["fleet_workers"] == 2
+
+
+# ------------------------------------------- one device per replica
+
+#: serves a fixed workload on a 4-replica reduced engine fleet (advanced
+#: on 4 threads) over ``argv[1]`` virtual CPU devices; prints where each
+#: replica's params, cache and slot state live, and what the fleet served
+_DEVICE_FLEET = textwrap.dedent("""
+    import os, sys
+    os.environ["XLA_FLAGS"] = (
+        f"--xla_force_host_platform_device_count={sys.argv[1]}"
+    )
+    import json
+    import jax
+    from repro.api import AgentService
+    from repro.api.backend import AgentSpec, InferenceSpec
+    from repro.configs import get_config
+    from repro.models import Model
+
+    model = Model(get_config("granite-3-2b").reduced(vocab=128))
+    params = model.init(jax.random.PRNGKey(0))
+    svc = AgentService.engine(
+        model, params, "justitia", replicas=4, router="round_robin",
+        pool_tokens=256, block_size=16, max_batch=2, cache_len=64,
+        token_scale=1, time_scale=1.0, fleet_workers=4,
+    )
+    for i in range(8):
+        svc.submit(AgentSpec(
+            stages=[[InferenceSpec(16 + i, 12)], [InferenceSpec(12, 8)]],
+            arrival=0.5 * i, name=f"a{i}",
+        ))
+    res = svc.drain()
+    placed = [
+        [sorted(d.id for d in x.devices())
+         for x in (e.params["embed"], e.cache["k"], e._d_state)]
+        for e in (c.engine for c in svc.backend.children)
+    ]
+    print("RESULTS::" + json.dumps({
+        "n_devices": len(jax.devices()),
+        "placed": placed,
+        "finish": {str(k): v for k, v in sorted(res.finish.items())},
+        "tokens": {str(h.agent_id): list(map(int, h.tokens))
+                   for h in svc.handles.values()},
+    }))
+""")
+
+
+def _device_fleets(*n_devices: int) -> list[dict]:
+    """Run ``_DEVICE_FLEET`` once per device count, side by side."""
+    env = dict(os.environ, PYTHONPATH="src")
+    env.pop("XLA_FLAGS", None)
+    procs = [
+        subprocess.Popen(
+            [sys.executable, "-c", _DEVICE_FLEET, str(n)],
+            stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
+            env=env,
+            cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        )
+        for n in n_devices
+    ]
+    out = []
+    for proc in procs:
+        stdout, stderr = proc.communicate(timeout=600)
+        assert proc.returncode == 0, stderr[-3000:]
+        line = [l for l in stdout.splitlines() if l.startswith("RESULTS::")]
+        assert line, stdout[-2000:]
+        out.append(json.loads(line[0][len("RESULTS::"):]))
+    return out
+
+
+def test_engine_fleet_one_device_per_replica():
+    """On 4 (virtual CPU) devices, replica k's params, cache and slot
+    state live on device k — and the fleet serves exactly what the same
+    fleet serves with every replica on one device."""
+    four, one = _device_fleets(4, 1)
+    assert four["n_devices"] == 4 and one["n_devices"] == 1
+    assert four["placed"] == [[[k]] * 3 for k in range(4)]
+    assert one["placed"] == [[[0]] * 3] * 4
+    assert len(four["finish"]) == 8
+    assert four["finish"] == one["finish"]
+    assert four["tokens"] == one["tokens"]
