@@ -79,6 +79,17 @@ behavioural oracle that pins these rules:
   swapped membership is an O(1) rid-set.  Scheduler ``Request`` views and
   their ``kv_token_time`` costs are cached per request, so key evaluation
   stops allocating.
+
+Host phases
+-----------
+Each phase of the host loop opens a profiler span ``engine.<phase>`` and
+adds its self time to ``metrics["<phase>_s"]`` (``repro.engine.trace``):
+``step``, and inside it ``admit`` (with each ``prefill`` pass and
+swap-in, ``swap``), the window's ``prep`` (with its swap-outs),
+``device_wait`` (dispatch to tokens on the host) and the token
+``replay``.  ``prefill_passes`` counts prefill dispatches.  A few phases
+open per window and none per token.  Each request carries host stamps,
+``t_queued`` and ``t_admit``, that no scheduling decision reads.
 """
 
 from __future__ import annotations
@@ -86,6 +97,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import heapq
+import time
 from typing import Any, Optional
 
 import jax
@@ -95,6 +107,7 @@ import numpy as np
 from repro.core.cost import InferenceSpec, kv_token_time
 from repro.core.queueing import OrderedQueue
 from repro.core.schedulers import AgentScheduler, Request
+from repro.engine.trace import Phases
 from repro.kvcache.allocator import BlockAllocator
 from repro.kvcache.prefix import PrefixAwareAllocator
 from repro.models import Model
@@ -266,6 +279,11 @@ class EngineRequest:
     done: bool = False
     #: measured prefix-cache hit at admission (engine-scale tokens)
     cached_tokens: int = 0
+    #: host stamps (``time.perf_counter``): pushed into the waiting queue,
+    #: and popped from it by admission, before its prefill.  Diagnostics
+    #: only: no scheduler key, clock or event reads them
+    t_queued: float = 0.0
+    t_admit: float = 0.0
     swapped_kv: Any = None         # host copy when swapped out
     _last_tok: int = 0
     _sched_req: Optional[Request] = dataclasses.field(
@@ -521,7 +539,14 @@ class ServeEngine:
                         "prefill_tokens_saved": 0, "prefix_hits": 0,
                         "fused_slices": 0, "admission_deferrals": 0,
                         "suspensions": 0, "resumes": 0,
-                        "suspend_spills": 0}
+                        "suspend_spills": 0, "prefill_passes": 0,
+                        # self times of the host phases (seconds)
+                        "step_s": 0.0, "admit_s": 0.0, "prefill_s": 0.0,
+                        "swap_s": 0.0, "prep_s": 0.0,
+                        "device_wait_s": 0.0, "replay_s": 0.0}
+        #: ``with self._phase(name, **args)``: the host phase's profiler
+        #: span ``engine.<name>`` and its self time in ``metrics``
+        self._phase = Phases(self.metrics).phase
         # per-agent prefix-cache accounting (engine-scale tokens)
         self.agent_prefill_tokens: dict[int, int] = {}
         self.agent_hit_tokens: dict[int, int] = {}
@@ -777,6 +802,7 @@ class ServeEngine:
             hints = agent.hints[agent.next_stage]
         agent.next_stage += 1
         agent.live += len(stage)
+        t_queued = time.perf_counter()
         for i, (prompt, d) in enumerate(stage):
             self.waiting.push(
                 EngineRequest(
@@ -789,6 +815,7 @@ class ServeEngine:
                         float(hints[i])
                         if hints is not None and i < len(hints) else 0.0
                     ),
+                    t_queued=t_queued,
                 )
             )
             self._rid += 1
@@ -807,11 +834,13 @@ class ServeEngine:
             raise RuntimeError("re-entrant step() from a listener callback")
         self._in_step = True
         try:
-            with jax.default_device(self.device):
+            with jax.default_device(self.device), \
+                    self._phase("step", now=self.now):
                 start = self.now
                 self._release_arrivals()
                 self._release_resumes()
-                self._admit()
+                with self._phase("admit"):
+                    self._admit()
                 if limit is not None:
                     # the admission pass may itself advance the clock
                     # (chunked prefill cost); shrink the decode budget so a
@@ -980,6 +1009,7 @@ class ServeEngine:
                     break
                 self.waiting.popleft()
                 self.alloc.admit(req.rid, len(req.prompt))
+            req.t_admit = time.perf_counter()
             batch.append(req)
         if batch:
             self._prefill_batch(batch)
@@ -1016,6 +1046,7 @@ class ServeEngine:
                     return
             self.waiting.popleft()
             self.alloc.admit(req.rid, len(req.prompt))
+        req.t_admit = time.perf_counter()
         p = len(req.prompt)
         hit = req.cached_tokens
         slot = self.slot_free.pop()
@@ -1061,17 +1092,19 @@ class ServeEngine:
         """Write the first ``n`` prompt tokens' K/V into the request's slot
         via the batched prefill program (single row, 64-token bucket)."""
         bucket = prompt_bucket(n)
-        toks = np.zeros((1, bucket), np.int32)
-        toks[0, :n] = req.prompt[:n]
-        self.cache, nxt = _prefill_write_jit(
-            self.model, self.cache_len, self.prefill_chunk,
-            self.params, self.cache,
-            jnp.asarray(toks), jnp.asarray([n], dtype=jnp.int32),
-            jnp.asarray([req.slot], dtype=jnp.int32),
-        )
-        if fetch_tok:
-            self.metrics["host_syncs"] += 1
-            return int(np.asarray(nxt)[0])
+        self.metrics["prefill_passes"] += 1
+        with self._phase("prefill", n=1, bucket=bucket, pad=1):
+            toks = np.zeros((1, bucket), np.int32)
+            toks[0, :n] = req.prompt[:n]
+            self.cache, nxt = _prefill_write_jit(
+                self.model, self.cache_len, self.prefill_chunk,
+                self.params, self.cache,
+                jnp.asarray(toks), jnp.asarray([n], dtype=jnp.int32),
+                jnp.asarray([req.slot], dtype=jnp.int32),
+            )
+            if fetch_tok:
+                self.metrics["host_syncs"] += 1
+                return int(np.asarray(nxt)[0])
         return None
 
     def _fused_to_decoder(self, req: EngineRequest, first_tok: int) -> None:
@@ -1130,19 +1163,22 @@ class ServeEngine:
             else:
                 bucket = max(max(p, 1) for p in plens)
                 k_pad = 1
-            toks = np.zeros((k_pad, bucket), np.int32)
-            lens = np.ones(k_pad, np.int32)              # dummy rows: 1 tok
-            slots = np.full(k_pad, self.max_batch, np.int32)   # OOB: dropped
-            for i, req in enumerate(group):
-                toks[i, : plens[i]] = req.prompt
-                lens[i] = plens[i]
-                slots[i] = req.slot
-            self.cache, nxt = _prefill_write_jit(
-                self.model, self.cache_len, self.prefill_chunk,
-                self.params, self.cache,
-                jnp.asarray(toks), jnp.asarray(lens), jnp.asarray(slots),
-            )
-            nxt_host = np.asarray(nxt)[:k]
+            self.metrics["prefill_passes"] += 1
+            with self._phase("prefill", n=k, bucket=bucket, pad=k_pad):
+                toks = np.zeros((k_pad, bucket), np.int32)
+                lens = np.ones(k_pad, np.int32)          # dummy rows: 1 tok
+                # out-of-bounds slots: padding rows, dropped
+                slots = np.full(k_pad, self.max_batch, np.int32)
+                for i, req in enumerate(group):
+                    toks[i, : plens[i]] = req.prompt
+                    lens[i] = plens[i]
+                    slots[i] = req.slot
+                self.cache, nxt = _prefill_write_jit(
+                    self.model, self.cache_len, self.prefill_chunk,
+                    self.params, self.cache,
+                    jnp.asarray(toks), jnp.asarray(lens), jnp.asarray(slots),
+                )
+                nxt_host = np.asarray(nxt)[:k]
             self.metrics["host_syncs"] += 1
             for req, p, tok in zip(group, plens, nxt_host):
                 self.slot_last_tok[req.slot] = tok
@@ -1191,24 +1227,27 @@ class ServeEngine:
 
     def _stage_out(self, req: EngineRequest, slot: int) -> None:
         """Copy slot ``slot``'s cache rows into a host staging buffer."""
-        dev = _gather_slot_jit(self.cache, slot)
         self.metrics["host_syncs"] += 1
-        if self._staging:
-            buf = self._staging.pop()
-            for dst, src in zip(jax.tree.leaves(buf), jax.tree.leaves(dev)):
-                np.copyto(dst, np.asarray(src))
-            req.swapped_kv = buf
-        else:
-            # np.array (not asarray): on the CPU backend asarray is a
-            # zero-copy READ-ONLY view of device memory — the staging pool
-            # needs owned, writable host buffers it can recycle
-            req.swapped_kv = jax.tree.map(np.array, dev)
+        with self._phase("swap"):
+            dev = _gather_slot_jit(self.cache, slot)
+            if self._staging:
+                buf = self._staging.pop()
+                for dst, src in zip(jax.tree.leaves(buf),
+                                    jax.tree.leaves(dev)):
+                    np.copyto(dst, np.asarray(src))
+                req.swapped_kv = buf
+            else:
+                # np.array (not asarray): on the CPU backend asarray is a
+                # zero-copy READ-ONLY view of device memory — the staging
+                # pool needs owned, writable host buffers it can recycle
+                req.swapped_kv = jax.tree.map(np.array, dev)
 
     def _restore_slot(self, req: EngineRequest) -> None:
         slot = self.slot_free.pop()
         req.slot = slot
         self.slot_req[slot] = req
-        self.cache = _scatter_slot_jit(self.cache, req.swapped_kv, slot)
+        with self._phase("swap"):
+            self.cache = _scatter_slot_jit(self.cache, req.swapped_kv, slot)
         self.metrics["host_syncs"] += 1
         # recycling the staged buffer is safe without an explicit sync: it
         # is only overwritten inside a later _stage_out, whose device->host
@@ -1479,98 +1518,105 @@ class ServeEngine:
     def _decode_once(self, limit: Optional[int] = None) -> int:
         if not self.slot_req and self._pf is None:
             return 1
-        # grow each running sequence by one token (may trigger swaps)
-        for slot in sorted(self.slot_req):
-            req = self.slot_req.get(slot)
-            if req is None:
-                continue
-            while not self.alloc.append_token(req.rid):
-                if not self._swap_out_worst():
-                    break
-                if req.rid not in self._swapped_rids:
+        with self._phase("prep"):
+            # grow each running sequence by one token (may trigger swaps)
+            for slot in sorted(self.slot_req):
+                req = self.slot_req.get(slot)
+                if req is None:
                     continue
-                break
-            # note: if req itself was swapped out it no longer decodes
-        active = sorted(self.slot_req)
-        if not active and self._pf is None:
-            return 1
-        k = self._window_size(limit)
-        snapshot = [(slot, self.slot_req[slot]) for slot in active]
-        if k > 1:
-            # commit the window's remaining token growth up front (the
-            # step-1 append already ran above; a request completing at
-            # window step r appends exactly r tokens, like the reference's
-            # per-step growth loop) — _window_size proved it all fits, so
-            # no swap decision is being skipped
-            for slot, req in snapshot:
-                extra = min(k, req.max_new_tokens - req.generated) - 1
-                if extra and not self.alloc.append_tokens(req.rid, extra):
-                    raise AssertionError("window over-committed the pool")
-        if self._slots_stale:
-            self._refresh_device_slots()
-        pf = self._pf
+                while not self.alloc.append_token(req.rid):
+                    if not self._swap_out_worst():
+                        break
+                    if req.rid not in self._swapped_rids:
+                        continue
+                    break
+                # note: if req itself was swapped out it no longer decodes
+            active = sorted(self.slot_req)
+            if not active and self._pf is None:
+                return 1
+            k = self._window_size(limit)
+            snapshot = [(slot, self.slot_req[slot]) for slot in active]
+            if k > 1:
+                # commit the window's remaining token growth up front (the
+                # step-1 append already ran above; a request completing at
+                # window step r appends exactly r tokens, like the
+                # reference's per-step growth loop) — _window_size proved
+                # it all fits, so no swap decision is being skipped
+                for slot, req in snapshot:
+                    extra = min(k, req.max_new_tokens - req.generated) - 1
+                    if extra and not self.alloc.append_tokens(req.rid, extra):
+                        raise AssertionError("window over-committed the pool")
+            if self._slots_stale:
+                self._refresh_device_slots()
+            pf = self._pf
+            if pf is not None:
+                # slice the next k prompt chunks host-side; the fused
+                # program advances one per scanned step alongside the
+                # decoders
+                chunk = self.prefill_chunk
+                sl = np.zeros((k, chunk), np.int32)
+                for j in range(k):
+                    seg = pf.req.prompt[pf.written + j * chunk:
+                                        pf.written + (j + 1) * chunk]
+                    sl[j, :len(seg)] = seg
+                meta = np.array([pf.slot, pf.written, pf.total], np.int32)
+        with self._phase("device_wait", k=k, batch=len(snapshot),
+                         fused=pf is not None):
+            if pf is not None:
+                self.cache, self._d_state, toks_dev = _fused_window_jit(
+                    self.model, k, chunk, self.params, self.cache,
+                    self._d_state, jnp.asarray(sl), jnp.asarray(meta),
+                )
+                out = np.asarray(toks_dev)   # (k, B+1): THE per-window sync
+            else:
+                self.cache, self._d_state, toks_dev = _decode_window_jit(
+                    self.model, k, self.params, self.cache, self._d_state
+                )
+                toks = np.asarray(toks_dev)  # (k, B): THE per-window sync
         if pf is not None:
-            # slice the next k prompt chunks host-side; the fused program
-            # advances one per scanned step alongside the decoders
-            chunk = self.prefill_chunk
-            sl = np.zeros((k, chunk), np.int32)
-            for j in range(k):
-                seg = pf.req.prompt[pf.written + j * chunk:
-                                    pf.written + (j + 1) * chunk]
-                sl[j, :len(seg)] = seg
-            meta = np.array([pf.slot, pf.written, pf.total], np.int32)
-            self.cache, self._d_state, toks_dev = _fused_window_jit(
-                self.model, k, chunk, self.params, self.cache,
-                self._d_state, jnp.asarray(sl), jnp.asarray(meta),
-            )
-            out = np.asarray(toks_dev)       # (k, B+1): THE per-window sync
             toks, pf_toks = out[:, :-1], out[:, -1]
             self.metrics["fused_slices"] += k
-        else:
-            self.cache, self._d_state, toks_dev = _decode_window_jit(
-                self.model, k, self.params, self.cache, self._d_state
-            )
-            toks = np.asarray(toks_dev)      # (k, B): THE per-window sync
         self.metrics["host_syncs"] += 1
         self.metrics["decode_steps"] += k
         self.metrics["windows"] += 1
-
-        # replay the per-token bookkeeping host-side in exact step order;
-        # a request whose budget ran out at an earlier window step is
-        # frozen (mirrors the device-side rem mask)
-        rem0 = {slot: req.max_new_tokens - req.generated
-                for slot, req in snapshot}
-        for i in range(k):
-            if i:
-                self.now += 1
-            for slot, req in snapshot:
-                if i >= rem0[slot]:
-                    continue
-                req.generated += 1
-                self.metrics["tokens"] += 1
-                self._emit(
-                    "on_token", req.agent_id, req.rid, int(toks[i, slot]),
-                    float(self.now),
-                )
-                self.slot_last_tok[slot] = toks[i, slot]
-                self.slot_pos[slot] += 1
-                occ = len(req.prompt) + req.generated
-                self.sched.on_service(
-                    req.agent_id, kv_token_time=float(occ), decode_tokens=1.0
-                )
-                if self._grouped:
-                    self._dirty_agents.add(req.agent_id)
-                if req.generated >= req.max_new_tokens:
-                    self._complete(slot, req)
-        if pf is not None:
-            pf.written += k * self.prefill_chunk
-            if pf.written >= pf.total:
-                # slice exhaustion — the window's last step (the sizer
-                # capped K at exactly this): the final slice's argmax is
-                # the request's first token; it decodes from the next
-                # iteration on
-                self._pf = None
-                self._fused_to_decoder(pf.req, int(pf_toks[k - 1]))
+        with self._phase("replay"):
+            # replay the per-token bookkeeping host-side in exact step
+            # order; a request whose budget ran out at an earlier window
+            # step is frozen (mirrors the device-side rem mask)
+            rem0 = {slot: req.max_new_tokens - req.generated
+                    for slot, req in snapshot}
+            for i in range(k):
+                if i:
+                    self.now += 1
+                for slot, req in snapshot:
+                    if i >= rem0[slot]:
+                        continue
+                    req.generated += 1
+                    self.metrics["tokens"] += 1
+                    self._emit(
+                        "on_token", req.agent_id, req.rid,
+                        int(toks[i, slot]), float(self.now),
+                    )
+                    self.slot_last_tok[slot] = toks[i, slot]
+                    self.slot_pos[slot] += 1
+                    occ = len(req.prompt) + req.generated
+                    self.sched.on_service(
+                        req.agent_id, kv_token_time=float(occ),
+                        decode_tokens=1.0,
+                    )
+                    if self._grouped:
+                        self._dirty_agents.add(req.agent_id)
+                    if req.generated >= req.max_new_tokens:
+                        self._complete(slot, req)
+            if pf is not None:
+                pf.written += k * self.prefill_chunk
+                if pf.written >= pf.total:
+                    # slice exhaustion — the window's last step (the sizer
+                    # capped K at exactly this): the final slice's argmax
+                    # is the request's first token; it decodes from the
+                    # next iteration on
+                    self._pf = None
+                    self._fused_to_decoder(pf.req, int(pf_toks[k - 1]))
         return k
 
     def _complete(self, slot: int, req: EngineRequest) -> None:
