@@ -131,7 +131,9 @@ def _decode_window_jit(model, k: int, params, cache, state):
     runs out mid-window freezes in place — exactly what the reference
     engine's stale freed-slot rows look like — so a window may span final
     completions.  Returns the K x B sampled tokens — the ONLY thing the
-    host needs per window."""
+    host needs per window.  The donated cache rides the scan's carry and
+    ``Model.decode`` writes only each slot's new row into it, so the
+    window updates that one buffer in place."""
 
     def body(carry, _):
         cache, state = carry

@@ -223,13 +223,13 @@ def cache_pspecs(cfg: ModelConfig, mesh: Mesh, cache_struct,
         t = batch_axes(mesh) if seq_axis == "batch" else "model"
     else:
         t = None
-    # hd and T cannot both live on "model"
+    # the feature dim and T cannot both live on "model"
     hd = None if t == "model" else "model"
 
     def one(path, leaf):
         name = str(path[-1].key)
-        if name in ("k", "v"):           # (L,B,T,nkv,hd)
-            return P(None, b, t, None, hd)
+        if name in ("k", "v"):           # (L,B,T,nkv*hd)
+            return P(None, b, t, hd)
         if name in ("cross_k", "cross_v"):
             return P(None, b, None, None, hd)
         if name == "kv_pos":             # (L,B,T)

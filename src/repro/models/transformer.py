@@ -46,29 +46,33 @@ def _dtype(cfg: ModelConfig):
     return jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
 
 
+def kv_rows(kv):
+    """(..., n_kv, head_dim) keys or values -> (..., n_kv*head_dim) cache
+    rows.  The cache keeps rows, not heads: at the published widths the
+    row is a multiple of 128 wide, so the TPU's (8,128) tiles pad nothing,
+    where a 64- or 80-wide head pads to 128."""
+    return kv.reshape(*kv.shape[:-2], kv.shape[-2] * kv.shape[-1])
+
+
+def kv_heads(rows, n_kv: int):
+    """(..., n_kv*head_dim) cache rows -> (..., n_kv, head_dim) heads."""
+    return rows.reshape(*rows.shape[:-1], n_kv, rows.shape[-1] // n_kv)
+
+
 def update_cache(cache_kv, new_kv, pos):
-    """cache_kv: (B,T,n,h); new_kv: (B,S,n,h); pos: (B,) write offsets."""
+    """cache_kv: (B,T,F); new_kv: (B,S,F); pos: (B,) write offsets."""
 
     def upd(c, n, p):
-        return jax.lax.dynamic_update_slice(c, n, (p, 0, 0))
+        return jax.lax.dynamic_update_slice(c, n, (p, 0))
 
     return jax.vmap(upd)(cache_kv, new_kv, pos)
 
 
-def update_pos(kv_pos, pos, s):
-    """kv_pos: (B,T) slot-position tensor; write arange(pos, pos+s)."""
-
-    def upd(kp, p):
-        new = p + jnp.arange(s, dtype=kp.dtype)
-        return jax.lax.dynamic_update_slice(kp, new, (p,))
-
-    return jax.vmap(upd)(kv_pos, pos)
-
-
 def update_pos_masked(kv_pos, pos, s, lens):
-    """``update_pos`` with per-row valid lengths: positions at or beyond a
-    row's true length are written as -1 (invalid slot), so padded chunk
-    tails never become attendable cache entries."""
+    """Write positions ``arange(pos, pos+s)`` into each row of ``kv_pos``
+    (B,T), with positions at or beyond a row's true length written as -1
+    (invalid slot), so padded chunk tails never become attendable cache
+    entries."""
 
     def upd(kp, p, ln):
         new = p + jnp.arange(s, dtype=kp.dtype)
@@ -78,25 +82,40 @@ def update_pos_masked(kv_pos, pos, s, lens):
     return jax.vmap(upd)(kv_pos, pos, lens)
 
 
-def ring_update_cache(cache_kv, new_kv, pos):
-    """SWA ring buffer: write one token at slot pos % T.  new_kv: (B,1,n,h)."""
-    t = cache_kv.shape[1]
-    slot = pos % t
+def decode_attention(p_attn, h, pos, layer, k_cache, v_cache, kv_pos,
+                     cfg: ModelConfig, ring: bool):
+    """One decode step's self-attention at ``layer`` of the layer-stacked
+    caches, which it updates in place.
 
-    def upd(c, n, sl):
-        return jax.lax.dynamic_update_slice(c, n, (sl, 0, 0))
-
-    return jax.vmap(upd)(cache_kv, new_kv, slot)
-
-
-def ring_update_pos(kv_pos, pos):
-    t = kv_pos.shape[1]
-    slot = pos % t
-
-    def upd(kp, sl, p):
-        return jax.lax.dynamic_update_slice(kp, p[None].astype(kp.dtype), (sl,))
-
-    return jax.vmap(upd)(kv_pos, slot, pos)
+    ``k_cache``/``v_cache``: (L,B,T,n_kv*head_dim); ``kv_pos``: (L,B,T).
+    Each slot's new key, value and position land at ``(layer, slot,
+    row)``, where ``row`` is ``pos`` on a full cache and ``pos % T`` on a
+    sliding-window ring; the query then attends over ``layer``'s slice.
+    Only B rows are written, so a layer scan that carries the caches keeps
+    one buffer instead of rebuilding the stack every step.
+    """
+    b, t = kv_pos.shape[1], kv_pos.shape[2]
+    q, k_new, v_new = attention_qkv(
+        p_attn, h, pos[:, None], cfg.rope_theta, cfg.use_rope
+    )
+    at = (layer, jnp.arange(b), pos % t if ring else pos)
+    # "clip" clamps a row past the cache as dynamic_update_slice would
+    k_cache = k_cache.at[at].set(kv_rows(k_new[:, 0]).astype(k_cache.dtype),
+                                 mode="clip")
+    v_cache = v_cache.at[at].set(kv_rows(v_new[:, 0]).astype(v_cache.dtype),
+                                 mode="clip")
+    kv_pos = kv_pos.at[at].set(pos.astype(kv_pos.dtype), mode="clip")
+    kp = kv_pos[layer]
+    att = gqa_attention(
+        q,
+        kv_heads(k_cache[layer], cfg.n_kv_heads),
+        kv_heads(v_cache[layer], cfg.n_kv_heads),
+        window=cfg.sliding_window,
+        q_positions=pos[:, None],
+        kv_positions=kp,
+        kv_valid=kp >= 0,
+    )
+    return attention_out(p_attn, att), k_cache, v_cache, kv_pos
 
 
 # ===========================================================================
@@ -164,11 +183,13 @@ def dense_block_chunk(p, x, pos, positions, lens, k_cache, v_cache, kv_pos,
     q, k_new, v_new = attention_qkv(
         p["attn"], h, positions, cfg.rope_theta, cfg.use_rope
     )
-    k_cache = update_cache(k_cache, k_new, pos)
-    v_cache = update_cache(v_cache, v_new, pos)
+    k_cache = update_cache(k_cache, kv_rows(k_new), pos)
+    v_cache = update_cache(v_cache, kv_rows(v_new), pos)
     kv_pos = update_pos_masked(kv_pos, pos, x.shape[1], lens)
     att = attention_any(
-        q, k_cache, v_cache,
+        q,
+        kv_heads(k_cache, cfg.n_kv_heads),
+        kv_heads(v_cache, cfg.n_kv_heads),
         window=cfg.sliding_window,
         q_positions=positions,
         kv_positions=kv_pos,
@@ -184,29 +205,15 @@ def dense_block_chunk(p, x, pos, positions, lens, k_cache, v_cache, kv_pos,
     return x + y, k_cache, v_cache, kv_pos
 
 
-def dense_block_decode(p, x, pos, k_cache, v_cache, kv_pos, cfg: ModelConfig,
-                       ring: bool):
-    """One-token decode step against a (possibly ring) KV cache."""
+def dense_block_decode(p, x, pos, layer, k_cache, v_cache, kv_pos,
+                       cfg: ModelConfig, ring: bool):
+    """One-token decode step of block ``layer`` against the layer-stacked
+    (possibly ring) KV cache, updated in place (``decode_attention``)."""
     h = rms_norm(x, p["ln1"], cfg.norm_eps)
-    q, k_new, v_new = attention_qkv(
-        p["attn"], h, pos[:, None], cfg.rope_theta, cfg.use_rope
+    att, k_cache, v_cache, kv_pos = decode_attention(
+        p["attn"], h, pos, layer, k_cache, v_cache, kv_pos, cfg, ring
     )
-    if ring:
-        k_cache = ring_update_cache(k_cache, k_new, pos)
-        v_cache = ring_update_cache(v_cache, v_new, pos)
-        kv_pos = ring_update_pos(kv_pos, pos)
-    else:
-        k_cache = update_cache(k_cache, k_new, pos)
-        v_cache = update_cache(v_cache, v_new, pos)
-        kv_pos = update_pos(kv_pos, pos, 1)
-    att = gqa_attention(
-        q, k_cache, v_cache,
-        window=cfg.sliding_window,
-        q_positions=pos[:, None],
-        kv_positions=kv_pos,
-        kv_valid=kv_pos >= 0,
-    )
-    x = x + attention_out(p["attn"], att)
+    x = x + att
     h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
     if cfg.is_moe:
         y, _ = moe_mlp(p["moe"], h2, top_k=cfg.top_k,
@@ -496,7 +503,7 @@ class Model:
         cfg = self.cfg
         dtype = _dtype(cfg)
         t = min(cache_len, cfg.sliding_window) if cfg.sliding_window else cache_len
-        kv = lambda n: jnp.zeros((n, batch, t, cfg.n_kv_heads, cfg.head_dim),
+        kv = lambda n: jnp.zeros((n, batch, t, cfg.n_kv_heads * cfg.head_dim),
                                  dtype)
         pos = lambda n: jnp.full((n, batch, t), -1, jnp.int32)
         if cfg.kind in ("dense", "moe", "vlm"):
@@ -756,20 +763,26 @@ class Model:
         positions = idx[None, :]
         x = self._embed(params, tokens[None, :], positions)
 
-        def body(h, xs):
-            lp, kc, vc, kp = xs
+        def body(carry, xs):
+            h, kc, vc, kp = carry
+            lp, layer = xs
             hn = rms_norm(h, lp["ln1"], cfg.norm_eps)
             q, k_new, v_new = attention_qkv(
                 lp["attn"], hn, positions, cfg.rope_theta, cfg.use_rope
             )
-            kc = kc.at[slot, idx].set(k_new[0].astype(kc.dtype), mode="drop")
-            vc = vc.at[slot, idx].set(v_new[0].astype(vc.dtype), mode="drop")
-            kp = kp.at[slot, idx].set(
+            at = (layer, slot, idx)
+            kc = kc.at[at].set(kv_rows(k_new[0]).astype(kc.dtype),
+                               mode="drop")
+            vc = vc.at[at].set(kv_rows(v_new[0]).astype(vc.dtype),
+                               mode="drop")
+            kp = kp.at[at].set(
                 jnp.where(idx < total, idx, -1).astype(kp.dtype), mode="drop"
             )
-            kp_row = kp[slot][None]
+            kp_row = kp[layer, slot][None]
             att = attention_any(
-                q, kc[slot][None], vc[slot][None],
+                q,
+                kv_heads(kc[layer, slot][None], cfg.n_kv_heads),
+                kv_heads(vc[layer, slot][None], cfg.n_kv_heads),
                 window=cfg.sliding_window,
                 q_positions=positions,
                 kv_positions=kp_row,
@@ -782,11 +795,11 @@ class Model:
                                capacity_factor=cfg.capacity_factor)
             else:
                 y = gated_mlp(lp["mlp"], h2)
-            return h + y, (kc, vc, kp)
+            return (h + y, kc, vc, kp), None
 
-        x, (ks, vs, kps) = jax.lax.scan(
-            body, x, (params["blocks"], cache["k"], cache["v"],
-                      cache["kv_pos"])
+        (x, ks, vs, kps), _ = jax.lax.scan(
+            body, (x, cache["k"], cache["v"], cache["kv_pos"]),
+            (params["blocks"], jnp.arange(cfg.n_layers)),
         )
         cache = dict(cache, k=ks, v=vs, kv_pos=kps)
         last = jnp.clip(total - 1 - start, 0, s - 1)
@@ -797,6 +810,7 @@ class Model:
         """Copy prefill K/V (L,B,S,n,h) into the cache's first S slots."""
         cfg = self.cfg
         t = cache["k"].shape[2]
+        ks, vs = kv_rows(ks), kv_rows(vs)
         if cfg.sliding_window and t < s:
             # ring buffer smaller than the prompt: keep the last t tokens
             ks, vs = ks[:, :, -t:], vs[:, :, -t:]
@@ -804,8 +818,8 @@ class Model:
             kvp = jnp.broadcast_to(kvp[None, None], ks.shape[:3])
         else:
             pad = t - ks.shape[2]
-            ks = jnp.pad(ks, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
-            vs = jnp.pad(vs, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
+            ks = jnp.pad(ks, ((0, 0), (0, 0), (0, pad), (0, 0)))
+            vs = jnp.pad(vs, ((0, 0), (0, 0), (0, pad), (0, 0)))
             kvp = jnp.pad(
                 jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None, None],
                                  (ks.shape[0], ks.shape[1], s)),
@@ -819,25 +833,32 @@ class Model:
 
     def decode(self, params, cache: dict, tokens, pos):
         """One decode step.  tokens: (B,1) int32; pos: (B,) positions of the
-        new token.  Returns (logits (B,1,V), updated cache)."""
+        new token.  Returns (logits (B,1,V), updated cache).
+
+        The attention caches ride the layer scan's carry and each layer
+        writes only its B new rows (``decode_attention``), so a window
+        that scans this step and donates the cache holds one copy of it.
+        """
         cfg = self.cfg
         b = tokens.shape[0]
         x = self._embed(params, tokens, pos[:, None])
         ring = bool(cfg.sliding_window) and (
             "k" in cache and cache["k"].shape[2] == cfg.sliding_window
         )
+        if "k" in cache:
+            # the layer scan's carry, and the layer each iteration writes
+            init = (x, cache["k"], cache["v"], cache["kv_pos"])
+            layers = jnp.arange(cache["k"].shape[0])
 
         if cfg.kind in ("dense", "moe", "vlm"):
             def body(carry, xs):
-                h = carry
-                lp, kc, vc, kp = xs
-                h, kc, vc, kp = dense_block_decode(lp, h, pos, kc, vc, kp,
-                                                   cfg, ring)
-                return h, (kc, vc, kp)
+                h, kc, vc, kp = carry
+                lp, layer = xs
+                return dense_block_decode(lp, h, pos, layer, kc, vc, kp,
+                                          cfg, ring), None
 
-            x, (ks, vs, kps) = jax.lax.scan(
-                body, x, (params["blocks"], cache["k"], cache["v"],
-                          cache["kv_pos"])
+            (x, ks, vs, kps), _ = jax.lax.scan(
+                body, init, (params["blocks"], layers)
             )
             cache = dict(cache, k=ks, v=vs, kv_pos=kps)
             return self._logits(params, x), cache
@@ -847,11 +868,13 @@ class Model:
             enc_pos = jnp.broadcast_to(jnp.arange(f)[None], (b, f))
 
             def body(carry, xs):
-                h = carry
-                lp, kc, vc, kp, ckx, cvx = xs
-                h2, kc, vc, kp = dense_block_decode_selfonly(
-                    lp, h, pos, kc, vc, kp, cfg
+                h, kc, vc, kp = carry
+                lp, ckx, cvx, layer = xs
+                att, kc, vc, kp = decode_attention(
+                    lp["attn"], rms_norm(h, lp["ln1"], cfg.norm_eps), pos,
+                    layer, kc, vc, kp, cfg, ring,
                 )
+                h2 = h + att
                 hx = rms_norm(h2, lp["ln_x"], cfg.norm_eps)
                 qx = jnp.einsum("bsd,dnh->bsnh", hx, lp["xattn"]["wq"])
                 xat = gqa_attention(
@@ -862,12 +885,12 @@ class Model:
                 h2 = h2 + attention_out(lp["xattn"], xat)
                 hm = rms_norm(h2, lp["ln2"], cfg.norm_eps)
                 h2 = h2 + gated_mlp(lp["mlp"], hm)
-                return h2, (kc, vc, kp)
+                return (h2, kc, vc, kp), None
 
-            x, (ks, vs, kps) = jax.lax.scan(
-                body, x,
-                (params["dec_blocks"], cache["k"], cache["v"],
-                 cache["kv_pos"], cache["cross_k"], cache["cross_v"]),
+            (x, ks, vs, kps), _ = jax.lax.scan(
+                body, init,
+                (params["dec_blocks"], cache["cross_k"], cache["cross_v"],
+                 layers),
             )
             cache = dict(cache, k=ks, v=vs, kv_pos=kps)
             return self._logits(params, x), cache
@@ -903,8 +926,8 @@ class Model:
             shared = params["shared_attn"]
 
             def body(carry, xs):
-                h = carry
-                lp, mh, mconv, kc, vc, kp = xs
+                h, kc, vc, kp = carry
+                lp, mh, mconv, layer = xs
 
                 def mamba_one(hc, packed):
                     mp, ln, st, cv = packed
@@ -915,15 +938,14 @@ class Model:
                 h, (mh, mconv) = jax.lax.scan(
                     mamba_one, h, (lp["mamba"], lp["ln"], mh, mconv)
                 )
-                h, kc, vc, kp = dense_block_decode(shared, h, pos, kc, vc,
-                                                   kp, cfg, ring)
-                return h, (mh, mconv, kc, vc, kp)
+                h, kc, vc, kp = dense_block_decode(shared, h, pos, layer, kc,
+                                                   vc, kp, cfg, ring)
+                return (h, kc, vc, kp), (mh, mconv)
 
-            x, (mh, mconv, ks, vs, kps) = jax.lax.scan(
-                body, x,
+            (x, ks, vs, kps), (mh, mconv) = jax.lax.scan(
+                body, init,
                 (params["super_blocks"], cache["mamba_h"],
-                 cache["mamba_conv"], cache["k"], cache["v"],
-                 cache["kv_pos"]),
+                 cache["mamba_conv"], layers),
             )
             cache = dict(cache, mamba_h=mh, mamba_conv=mconv, k=ks, v=vs,
                          kv_pos=kps)
@@ -937,24 +959,3 @@ def _gather_last(x, lens):
     b = x.shape[0]
     idx = jnp.clip(lens - 1, 0, x.shape[1] - 1)
     return x[jnp.arange(b), idx][:, None, :]
-
-
-def dense_block_decode_selfonly(p, x, pos, k_cache, v_cache, kv_pos,
-                                cfg: ModelConfig):
-    """Self-attention part of a decoder block (cross-attn handled outside)."""
-    h = rms_norm(x, p["ln1"], cfg.norm_eps)
-    q, k_new, v_new = attention_qkv(
-        p["attn"], h, pos[:, None], cfg.rope_theta, cfg.use_rope
-    )
-    k_cache = update_cache(k_cache, k_new, pos)
-    v_cache = update_cache(v_cache, v_new, pos)
-    kv_pos = update_pos(kv_pos, pos, 1)
-    att = gqa_attention(
-        q, k_cache, v_cache,
-        q_positions=pos[:, None],
-        kv_positions=kv_pos,
-        kv_valid=kv_pos >= 0,
-    )
-    return x + attention_out(p["attn"], att), k_cache, v_cache, kv_pos
-
-

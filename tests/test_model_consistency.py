@@ -1,6 +1,8 @@
 """Model correctness properties:
 
   * prefill + incremental decode == teacher-forced forward (per arch);
+  * a decode step writes each slot's new cache row in place and nothing
+    else;
   * mLSTM parallel form == recurrent scan form (short sequences);
   * SWA ring-buffer decode == full-cache decode with a window mask;
   * ragged prompts: per-row lens mask the cache correctly.
@@ -16,9 +18,17 @@ import pytest
 from repro.configs import ALL_ARCHS, get_config
 from repro.models import Model
 from repro.models import ssm as ssm_mod
+from repro.models.layers import (
+    attention_out,
+    attention_qkv,
+    gated_mlp,
+    gqa_attention,
+    rms_norm,
+)
+from repro.models.transformer import kv_heads, kv_rows
 
 # full-zoo consistency sweeps dominate tier-1 runtime; run via `pytest -m slow`
-pytestmark = pytest.mark.slow
+slow = pytest.mark.slow
 
 B, S, EXTRA = 2, 12, 3
 
@@ -31,6 +41,7 @@ def _cfg(arch):
     return cfg
 
 
+@slow
 @pytest.mark.parametrize("arch", ALL_ARCHS)
 def test_decode_matches_teacher_forcing(arch):
     cfg = _cfg(arch)
@@ -71,6 +82,81 @@ def test_decode_matches_teacher_forcing(arch):
         )
 
 
+def _decode_by_slices(model, params, cache, tokens, pos):
+    """One dense decode step written layer by layer: slice the layer's
+    cache, write each slot's new row with ``dynamic_update_slice``, attend
+    over the slice.  Returns (logits, k, v, kv_pos) with the caches
+    restacked."""
+    cfg = model.cfg
+    x = model._embed(params, tokens, pos[:, None])
+    write = jax.vmap(
+        lambda c, n, p: jax.lax.dynamic_update_slice(c, n, (p, 0, 0))
+    )
+    write_pos = jax.vmap(
+        lambda c, p: jax.lax.dynamic_update_slice(c, p[None], (p,))
+    )
+    ks, vs, kps = [], [], []
+    for layer in range(cfg.n_layers):
+        lp = jax.tree.map(lambda a: a[layer], params["blocks"])
+        h = rms_norm(x, lp["ln1"], cfg.norm_eps)
+        q, k_new, v_new = attention_qkv(
+            lp["attn"], h, pos[:, None], cfg.rope_theta, cfg.use_rope
+        )
+        kc = write(kv_heads(cache["k"][layer], cfg.n_kv_heads), k_new, pos)
+        vc = write(kv_heads(cache["v"][layer], cfg.n_kv_heads), v_new, pos)
+        kp = write_pos(cache["kv_pos"][layer], pos)
+        att = gqa_attention(q, kc, vc, q_positions=pos[:, None],
+                            kv_positions=kp, kv_valid=kp >= 0)
+        x = x + attention_out(lp["attn"], att)
+        x = x + gated_mlp(lp["mlp"], rms_norm(x, lp["ln2"], cfg.norm_eps))
+        ks.append(kv_rows(kc))
+        vs.append(kv_rows(vc))
+        kps.append(kp)
+    return (model._logits(params, x), jnp.stack(ks), jnp.stack(vs),
+            jnp.stack(kps))
+
+
+def test_decode_writes_each_slots_row_in_place():
+    """Slots at ragged positions, one of them finished and held frozen the
+    way a decode window holds it: the step writes K/V/kv_pos only at
+    (layer, slot, pos), leaves every other row bitwise as it was, and
+    gives the logits of the slice-by-slice step."""
+    cfg = get_config("granite-3-2b").reduced()
+    model = Model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    lens = jnp.array([9, 4, 12], jnp.int32)
+    toks = jax.random.randint(
+        jax.random.PRNGKey(1), (3, 12), 0, cfg.vocab
+    ).astype(jnp.int32)
+    _, cache = model.prefill(params, {"tokens": toks, "lens": lens},
+                             cache_len=16)
+    by_slices = jax.jit(_decode_by_slices, static_argnums=0)
+    _, *kv = by_slices(model, params, cache, jnp.array([[5], [7], [11]]),
+                       lens)
+    cache = dict(zip(("k", "v", "kv_pos"), kv))
+    # slot 1 has finished: it stays at its position with its last token
+    pos = lens + jnp.array([1, 0, 1], jnp.int32)
+    tokens = jnp.array([[3], [7], [2]], jnp.int32)
+    logits, new = jax.jit(model.decode)(params, cache, tokens, pos)
+    ref_logits, *ref = by_slices(model, params, cache, tokens, pos)
+
+    written = np.zeros(cache["kv_pos"].shape, bool)
+    written[:, np.arange(3), np.asarray(pos)] = True
+    for name, want in zip(("k", "v", "kv_pos"), ref):
+        got, old = np.asarray(new[name]), np.asarray(cache[name])
+        np.testing.assert_array_equal(got[~written], old[~written],
+                                      err_msg=name)
+        np.testing.assert_allclose(got[written], np.asarray(want)[written],
+                                   rtol=1e-6, atol=1e-6, err_msg=name)
+    np.testing.assert_array_equal(
+        np.asarray(new["kv_pos"])[:, np.arange(3), np.asarray(pos)],
+        np.broadcast_to(np.asarray(pos), (cfg.n_layers, 3)),
+    )
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(ref_logits),
+                               rtol=1e-5, atol=1e-5)
+
+
+@slow
 def test_mlstm_parallel_equals_recurrent():
     key = jax.random.PRNGKey(0)
     p = ssm_mod.init_mlstm(key, 64, 2, 32, jnp.float32)
@@ -82,6 +168,7 @@ def test_mlstm_parallel_equals_recurrent():
     )
 
 
+@slow
 def test_mlstm_decode_continues_forward():
     key = jax.random.PRNGKey(0)
     p = ssm_mod.init_mlstm(key, 64, 2, 32, jnp.float32)
@@ -95,6 +182,7 @@ def test_mlstm_decode_continues_forward():
     )
 
 
+@slow
 def test_mamba2_decode_continues_forward():
     key = jax.random.PRNGKey(0)
     p = ssm_mod.init_mamba2(key, 64, 16, 4, jnp.float32)
@@ -108,6 +196,7 @@ def test_mamba2_decode_continues_forward():
     )
 
 
+@slow
 def test_swa_ring_buffer_matches_windowed_full_cache():
     """h2o-danube reduced: decode with the ring cache (T=window) must equal
     decode with a big full cache — SWA masking makes them equivalent."""
@@ -147,6 +236,7 @@ def test_swa_ring_buffer_matches_windowed_full_cache():
         )
 
 
+@slow
 def test_ragged_prompt_lens_respected():
     """Row 1's prompt is shorter; its cache slots beyond lens are masked, so
     its decode output must equal an unpadded run of the same prompt."""
@@ -187,6 +277,7 @@ def test_ragged_prompt_lens_respected():
     )
 
 
+@slow
 def test_mamba2_chunked_equals_sequential():
     key = jax.random.PRNGKey(0)
     p = ssm_mod.init_mamba2(key, 64, 16, 4, jnp.float32)
@@ -199,6 +290,7 @@ def test_mamba2_chunked_equals_sequential():
                                atol=1e-5, rtol=1e-4)
 
 
+@slow
 def test_mlstm_chunked_equals_sequential():
     key = jax.random.PRNGKey(0)
     p = ssm_mod.init_mlstm(key, 64, 2, 32, jnp.float32)
@@ -211,6 +303,7 @@ def test_mlstm_chunked_equals_sequential():
                                atol=1e-5, rtol=1e-4)
 
 
+@slow
 def test_chunked_attention_equals_full():
     from repro.models.layers import chunked_gqa_attention, gqa_attention
 
@@ -227,6 +320,7 @@ def test_chunked_attention_equals_full():
                                    atol=1e-5, rtol=1e-4)
 
 
+@slow
 def test_chunked_lm_loss_equals_plain():
     from repro.training import chunked_lm_loss, lm_loss
 

@@ -5,9 +5,11 @@ described (not attached) ``v5e:2x2`` topology, at granite-3-2b's published
 widths (40 layers, d_model 2048, vocab 49155, bf16) and the one-chip sizes
 ``chip_smoke.py`` serves at (``V5E_ENGINE_KW``).  Each program must compile,
 and its argument bytes plus temporaries must stay under the 15.75 GiB of
-HBM the compiler allows on a v5e.  Nothing runs, so no result or time is
-checked here; parameters and cache are ``ShapeDtypeStruct``s from
-``jax.eval_shape``.
+HBM the compiler allows on a v5e.  The decode windows, at granite's and
+h2o-danube-1.8b's widths, must also update the one donated KV cache in
+place: temporaries under a quarter of the cache and no copy of the whole
+stacked K or V.  Nothing runs, so no result or time is checked here;
+parameters and cache are ``ShapeDtypeStruct``s from ``jax.eval_shape``.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU compiler's library.
@@ -17,6 +19,7 @@ from __future__ import annotations
 
 import inspect
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -58,8 +61,9 @@ def one_chip():
 
 
 @pytest.fixture(scope="module")
-def shapes(one_chip):
-    model = Model(get_config("granite-3-2b"))
+def shapes_of(one_chip):
+    """``shapes_of(arch)``: the model and the argument shapes of its
+    engine programs, made once per architecture."""
     b, t = V5E_ENGINE_KW["max_batch"], V5E_ENGINE_KW["cache_len"]
 
     def sds(tree):
@@ -72,10 +76,24 @@ def shapes(one_chip):
     def i32(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
 
-    params = sds(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
-    cache = sds(jax.eval_shape(lambda: model.init_cache(None, b, t)))
-    row = sds(jax.eval_shape(lambda c: E._gather_slot_jit(c, 0), cache))
-    return model, params, cache, row, i32
+    made = {}
+
+    def make(arch):
+        if arch not in made:
+            model = Model(get_config(arch))
+            params = sds(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+            cache = sds(jax.eval_shape(lambda: model.init_cache(None, b, t)))
+            row = sds(jax.eval_shape(lambda c: E._gather_slot_jit(c, 0),
+                                     cache))
+            made[arch] = (model, params, cache, row, i32)
+        return made[arch]
+
+    return make
+
+
+@pytest.fixture(scope="module")
+def shapes(shapes_of):
+    return shapes_of("granite-3-2b")
 
 
 def lowerings(model, params, cache, row, i32):
@@ -119,3 +137,25 @@ def test_engine_program_fits_one_v5e(shapes, program):
         f"{program}: {used / 2**30:.2f} GiB of arguments + temporaries "
         f"exceeds the v5e's {V5E_HBM / 2**30} GiB"
     )
+
+
+@pytest.mark.parametrize("program", [
+    "decode_window_1", "decode_window_max", "fused_window_max",
+])
+@pytest.mark.parametrize("arch", ["granite-3-2b", "h2o-danube-1.8b"])
+def test_decode_window_updates_one_cache_in_place(shapes_of, arch, program):
+    shapes = shapes_of(arch)
+    cache = shapes[2]
+    compiled = lowerings(*shapes)[program]().compile()
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree.leaves(cache))
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < cache_bytes / 4, (
+        f"{arch} {program}: {temp / 2**30:.2f} GiB of temporaries against "
+        f"a {cache_bytes / 2**30:.2f} GiB cache"
+    )
+    # an instruction whose result (or async pair) is the stacked K or V
+    dims = ",".join(map(str, cache["k"].shape))
+    copies = re.findall(rf"= \(?\w+\[{dims}\][^=]* copy(?:-start)?\(",
+                        compiled.as_text())
+    assert not copies, f"{arch} {program}: whole-cache copies {copies}"
