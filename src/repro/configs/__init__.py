@@ -22,6 +22,7 @@ _MODULES = {
     "xlstm-350m": "xlstm_350m",
     "zamba2-2.7b": "zamba2_2_7b",
     "starcoder2-7b": "starcoder2_7b",
+    "granite-4.0-h-micro": "granite_4_0_h_micro",
     "llama2-7b": "llama2_7b_paper",
 }
 

@@ -64,7 +64,9 @@ behavioural oracle that pins these rules:
   (padded to the group's 64-token bucket, lens-masked, chunked by
   ``prefill_chunk`` through ``Model.prefill_chunked``), scattering every
   admitted slot's cache rows in the same jitted call that computes the
-  first sampled tokens.
+  first sampled tokens.  A model that cannot prefill a padded batch
+  exactly (``Model.ragged_prefill`` false) prefills one prompt a pass at
+  its own length.
 * **Consistent admission clock.**  Prefill iteration costs
   (``ceil(p / prefill_chunk) - 1`` each) are accumulated and applied to
   ``self.now`` ONCE at the end of the admission pass, so every admission
@@ -87,9 +89,12 @@ adds its self time to ``metrics["<phase>_s"]`` (``repro.engine.trace``):
 ``step``, and inside it ``admit`` (with each ``prefill`` pass and
 swap-in, ``swap``), the window's ``prep`` (with its swap-outs),
 ``device_wait`` (dispatch to tokens on the host) and the token
-``replay``.  ``prefill_passes`` counts prefill dispatches.  A few phases
-open per window and none per token.  Each request carries host stamps,
-``t_queued`` and ``t_admit``, that no scheduling decision reads.
+``replay``.  ``prefill_passes`` counts prefill dispatches,
+``prefill_tokens`` the prompt positions they prefilled and
+``prefill_rows`` the positions they computed (batch pad x bucket).  A
+few phases open per window and none per token.  Each request carries
+host stamps, ``t_queued`` and ``t_admit``, that no scheduling decision
+reads.
 """
 
 from __future__ import annotations
@@ -426,6 +431,14 @@ class ServeEngine:
                     f"family (dense/moe/vlm, no ring buffer); got "
                     f"kind={model.cfg.kind!r} ring={ring}"
                 )
+        if model.recurrent_state and (prefix_cache
+                                      or suspend_retention == "spill"):
+            # a reused prefix or a spilled slot would need a snapshot of
+            # the recurrent state at that position, which no cache keeps
+            raise ValueError(
+                "prefix_cache and suspend_retention='spill' need a cache "
+                f"of K/V only; kind={model.cfg.kind!r} keeps recurrent state"
+            )
         self._pf: Optional[_FusedPrefill] = None
         alloc_cls = PrefixAwareAllocator if prefix_cache else BlockAllocator
         self.alloc = alloc_cls(pool_tokens, block_size)
@@ -542,6 +555,7 @@ class ServeEngine:
                         "fused_slices": 0, "admission_deferrals": 0,
                         "suspensions": 0, "resumes": 0,
                         "suspend_spills": 0, "prefill_passes": 0,
+                        "prefill_tokens": 0, "prefill_rows": 0,
                         # self times of the host phases (seconds)
                         "step_s": 0.0, "admit_s": 0.0, "prefill_s": 0.0,
                         "swap_s": 0.0, "prep_s": 0.0,
@@ -560,10 +574,9 @@ class ServeEngine:
         mid-run: every power-of-two decode window up to ``max_window``,
         the batched prefill programs for the given 64-token prompt buckets
         (every power-of-two batch pad), and the slot gather/scatter pair.
-        Recurrent families (ssm/hybrid/encdec) prefill at exact prompt
-        lengths, which warmup cannot know — their first admission per
-        distinct length still compiles lazily; only the attention-cache
-        families get fully precompiled prefills.
+        A model without ``ragged_prefill`` (ssm, the shared-block hybrid,
+        encdec) prefills at exact prompt lengths, which warmup cannot know:
+        its first admission per distinct length still compiles lazily.
 
         Runs the real programs against the engine's own (donated) buffers:
         with no running slots the masked slot state is a no-op and the
@@ -601,7 +614,7 @@ class ServeEngine:
             self.cache = _clear_slot_kvpos_jit(self.cache, 0)
             jax.block_until_ready(self.cache["kv_pos"])
             self._slots_stale = True
-        batched_ok = self.model.cfg.kind in ("dense", "moe", "vlm")
+        batched_ok = self.model.ragged_prefill
         # cover the pow2 CEILING of max_batch: _prefill_batch pads a
         # k-request pass to 1 << (k-1).bit_length(), which exceeds
         # max_batch itself when max_batch is not a power of two
@@ -1095,7 +1108,9 @@ class ServeEngine:
         via the batched prefill program (single row, 64-token bucket)."""
         bucket = prompt_bucket(n)
         self.metrics["prefill_passes"] += 1
-        with self._phase("prefill", n=1, bucket=bucket, pad=1):
+        self.metrics["prefill_tokens"] += n
+        self.metrics["prefill_rows"] += bucket
+        with self._phase("prefill", n=1, bucket=bucket, pad=1, tokens=n):
             toks = np.zeros((1, bucket), np.int32)
             toks[0, :n] = req.prompt[:n]
             self.cache, nxt = _prefill_write_jit(
@@ -1135,19 +1150,19 @@ class ServeEngine:
     def _prefill_batch(self, batch: list[EngineRequest]) -> None:
         """Prefill every admitted request of this pass.
 
-        Attention-cache families run as ONE bucketed multi-sequence prefill
-        (padded to the group's 64-token bucket and to a power-of-two batch;
-        the lens mask keeps logits exact and invalid cache slots
-        unattendable, out-of-bounds padding slots are scatter-dropped).
-        Recurrent families (ssm/hybrid/encdec) prefill one sequence at a
-        time — padding would pollute their recurrent state — but still go
-        through the jitted scatter write.  The iteration cost of the pass,
-        sum(ceil(p/prefill_chunk) - 1), is applied to the clock ONCE at the
-        end so every admission decision and event stamp of the pass sees a
-        consistent ``now``.
+        A model with ``ragged_prefill`` runs ONE bucketed multi-sequence
+        prefill (padded to the group's 64-token bucket and to a
+        power-of-two batch; the lens mask keeps logits exact, invalid cache
+        slots unattendable and padded positions out of recurrent state,
+        and out-of-bounds padding slots are scatter-dropped).  The others
+        (ssm, the shared-block hybrid, encdec) prefill one sequence at a
+        time at its own length, still through the jitted scatter write.
+        The iteration cost of the pass, sum(ceil(p/prefill_chunk) - 1), is
+        applied to the clock ONCE at the end so every admission decision
+        and event stamp of the pass sees a consistent ``now``.
         """
         now0 = self.now
-        batched_ok = self.model.cfg.kind in ("dense", "moe", "vlm")
+        batched_ok = self.model.ragged_prefill
         groups = [batch] if batched_ok else [[r] for r in batch]
         for group in groups:
             k = len(group)
@@ -1166,7 +1181,10 @@ class ServeEngine:
                 bucket = max(max(p, 1) for p in plens)
                 k_pad = 1
             self.metrics["prefill_passes"] += 1
-            with self._phase("prefill", n=k, bucket=bucket, pad=k_pad):
+            self.metrics["prefill_tokens"] += sum(plens)
+            self.metrics["prefill_rows"] += k_pad * bucket
+            with self._phase("prefill", n=k, bucket=bucket, pad=k_pad,
+                             tokens=sum(plens)):
                 toks = np.zeros((k_pad, bucket), np.int32)
                 lens = np.ones(k_pad, np.int32)          # dummy rows: 1 tok
                 # out-of-bounds slots: padding rows, dropped

@@ -244,10 +244,10 @@ def cache_pspecs(cfg: ModelConfig, mesh: Mesh, cache_struct,
             return P(None, b, None)
         if name in ("slstm_c", "slstm_n", "slstm_h", "slstm_m"):
             return P(None, b, None, "model")
-        if name == "mamba_h":            # (NS,AE,B,H,P,N)
-            return P(None, None, b, "model", None, None)
-        if name == "mamba_conv":         # (NS,AE,B,W-1,C)
-            return P(None, None, b, None, "model")
+        if name == "mamba_h":            # (L,B,H,P,N)
+            return P(None, b, "model", None, None)
+        if name == "mamba_conv":         # (L,B,W-1,C)
+            return P(None, b, None, "model")
         return P(*([None] * leaf.ndim))
 
     return jax.tree_util.tree_map_with_path(one, cache_struct)

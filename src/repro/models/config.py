@@ -26,7 +26,8 @@ class Block(enum.Enum):
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
-    kind: str                      # dense | moe | ssm | hybrid | encdec | vlm
+    kind: str                      # dense | moe | ssm | hybrid | mamba2_hybrid
+                                   # | encdec | vlm
     n_layers: int
     d_model: int
     n_heads: int
@@ -37,7 +38,8 @@ class ModelConfig:
     # attention
     head_dim: int = 0              # 0 -> d_model // n_heads
     rope_theta: float = 500_000.0
-    use_rope: bool = True          # False -> learned absolute positions
+    position: str = "rope"         # rope | learned (absolute) | none
+    attn_scale: float = 0.0        # query-key scale; 0 -> head_dim ** -0.5
     sliding_window: int = 0        # 0 -> full attention
     max_position: int = 1_048_576  # for learned positions / rope cache
 
@@ -50,6 +52,9 @@ class ModelConfig:
     ssm_state: int = 0             # Mamba2 state size per head
     conv_width: int = 4            # Mamba2 short conv
     attn_every: int = 0            # hybrid: one shared attn block every k
+    # mamba2_hybrid: each layer's sequence mixer, "mamba" or "attention",
+    # every layer with its own weights and its own MLP
+    layer_types: tuple = ()
     # xLSTM: ratio of mLSTM blocks per sLSTM block (7:1 in the paper's
     # xLSTM[7:1]; we alternate per `slstm_every`)
     slstm_every: int = 2
@@ -60,6 +65,13 @@ class ModelConfig:
 
     # VLM
     n_image_tokens: int = 0        # anyres patch embeddings (stub frontend)
+
+    # granite's scalar multipliers: embeddings times
+    # ``embedding_multiplier``, logits divided by ``logits_scaling``, and
+    # (mamba2_hybrid only) each residual branch times ``residual_multiplier``
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
 
     # misc
     norm_eps: float = 1e-5
@@ -76,6 +88,13 @@ class ModelConfig:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
         if self.n_heads % self.n_kv_heads != 0:
             raise ValueError("n_heads must be divisible by n_kv_heads")
+        if self.layer_types and len(self.layer_types) != self.n_layers:
+            raise ValueError("layer_types must name every layer")
+        if self.position not in ("rope", "learned", "none"):
+            raise ValueError(f"unknown position encoding {self.position!r}")
+        if self.residual_multiplier != 1.0 and self.kind != "mamba2_hybrid":
+            raise ValueError("only the mamba2_hybrid stack serves a "
+                             "residual_multiplier")
 
     # ---------------------------------------------------------------- props
 
@@ -86,6 +105,15 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
+
+    @property
+    def qk_scale(self) -> float:
+        """The constant attention scores are multiplied by."""
+        return self.attn_scale or self.head_dim ** -0.5
+
+    @property
+    def use_rope(self) -> bool:
+        return self.position == "rope"
 
     def attn_shard_mode(self, model_par: int) -> str:
         """Resolve 'auto' against a model-parallel degree."""
@@ -131,6 +159,8 @@ class ModelConfig:
             top_k=min(self.top_k, 2) if self.top_k else 0,
             ssm_state=min(self.ssm_state, 16) if self.ssm_state else 0,
             attn_every=min(self.attn_every, 2) if self.attn_every else 0,
+            # one layer of each kind, in the published order
+            layer_types=tuple(dict.fromkeys(self.layer_types)),
             n_enc_layers=2 if self.n_enc_layers else 0,
             n_audio_frames=16 if self.n_enc_layers else 1500,
             n_image_tokens=8 if self.n_image_tokens else 0,

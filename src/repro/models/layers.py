@@ -72,6 +72,7 @@ def gqa_attention(
     q_positions=None,   # (B, S) absolute positions of queries; default arange
     kv_valid=None,      # (B, T) bool mask of valid cache slots (decode)
     kv_positions=None,  # (B, T) absolute positions of cache slots (ring SWA)
+    scale: Optional[float] = None,  # score scale; None -> 1/sqrt(head_dim)
 ):
     """Grouped-query attention with optional causal/sliding-window masking.
 
@@ -85,7 +86,7 @@ def gqa_attention(
 
     logits = jnp.einsum(
         "bsngh,btnh->bngst", qg, k, preferred_element_type=jnp.float32
-    ) * _qk_scale(hd)  # (B, nkv, qpk, S, T)
+    ) * (scale or _qk_scale(hd))  # (B, nkv, qpk, S, T)
 
     if q_positions is None:
         q_pos = jnp.arange(s)[None, :] + (causal_offset or 0)
@@ -118,6 +119,7 @@ def chunked_gqa_attention(
     kv_valid=None,
     chunk_q: int = 512,
     chunk_k: int = 1024,
+    scale: Optional[float] = None,
 ):
     """Flash-style chunked attention in pure JAX (lax.scan online softmax).
 
@@ -186,7 +188,7 @@ def chunked_gqa_attention(
     qp = q_positions.reshape(b, nq, cq)
     kp = kv_positions.reshape(b, nk, ck)
     kva = kv_valid.reshape(b, nk, ck)
-    scale = _qk_scale(hd)
+    scale = scale or _qk_scale(hd)
 
     def one_q_chunk(carry, qs):
         q_c, qp_c = qs          # (B,cq,nkv,qpk,hd), (B,cq)
@@ -247,18 +249,18 @@ def chunked_gqa_attention(
 
 def attention_any(
     q, k, v, *, window=0, q_positions=None, kv_positions=None,
-    kv_valid=None, full_threshold: int = 2048,
+    kv_valid=None, full_threshold: int = 2048, scale=None,
 ):
     """Dispatch: full-matrix attention for small S*T, chunked otherwise."""
     s, t = q.shape[1], k.shape[1]
     if s * t <= full_threshold * full_threshold or s == 1:
         return gqa_attention(
             q, k, v, window=window, q_positions=q_positions,
-            kv_positions=kv_positions, kv_valid=kv_valid,
+            kv_positions=kv_positions, kv_valid=kv_valid, scale=scale,
         )
     return chunked_gqa_attention(
         q, k, v, window=window, q_positions=q_positions,
-        kv_positions=kv_positions, kv_valid=kv_valid,
+        kv_positions=kv_positions, kv_valid=kv_valid, scale=scale,
     )
 
 

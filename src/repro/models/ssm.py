@@ -7,7 +7,7 @@ form used for training — mathematically equivalent to its recurrence and
 MXU-friendly (it is a decay-masked attention), matching how the xLSTM paper
 trains on accelerators.
 
-State layouts (per layer):
+State layouts (per layer; a model's cache stacks them layer first):
   mamba2:  h: (B, H, P, N)   conv: (B, W-1, d_conv_channels)
   mlstm:   C: (B, H, hd, hd)  n: (B, H, hd)  m: (B, H)
   slstm:   c,n,h: (B, H, hd)  m: (B, H)
@@ -38,8 +38,11 @@ def init_mamba2(key, d_model: int, d_state: int, conv_width: int, dtype):
     ks = jax.random.split(key, 6)
     scale = d_model ** -0.5
     return {
-        # fused input projection: [z, x, B, C, dt]
-        "w_in": (jax.random.normal(ks[0], (d_model, 2 * d_inner + 2 * n + h))
+        # fused input projection, out by in: rows [z, x, B, C, dt].  The
+        # model width stays minor, so a TPU keeps an output width that is
+        # no multiple of 128 (8512 for granite-4.0-h) in the layout the
+        # matmul reads, with no relayout of the weights per call
+        "w_in": (jax.random.normal(ks[0], (2 * d_inner + 2 * n + h, d_model))
                  * scale).astype(dtype),
         "conv_w": (jax.random.normal(ks[1], (conv_width, d_inner + 2 * n))
                    * 0.1).astype(dtype),
@@ -53,17 +56,24 @@ def init_mamba2(key, d_model: int, d_state: int, conv_width: int, dtype):
     }
 
 
-def _mamba2_project(p, x, conv_state=None):
+def _mamba2_sizes(p):
+    """(d_inner, heads, head size, d_state) of a Mamba2 block's weights."""
+    d_inner = p["w_out"].shape[0]
+    h = p["a_log"].shape[0]
+    n = (p["w_in"].shape[0] - 2 * d_inner - h) // 2
+    return d_inner, h, d_inner // h, n
+
+
+def _mamba2_project(p, x, conv_state=None, n_valid=None):
     """Shared projection+conv for train/prefill/decode.
 
-    x: (B, S, D).  Returns z, xs, bv, cv, dt and the new conv state.
+    x: (B, S, D).  Returns z, xs, bv, cv, dt and the new conv state: the
+    last W-1 conv inputs, or with ``n_valid`` (B,) each row's last W-1
+    inputs before its first padded position.
     """
-    d_model = x.shape[-1]
-    d_inner = 2 * d_model
-    h = p["a_log"].shape[0]
-    n = (p["w_in"].shape[1] - 2 * d_inner - h) // 2
+    d_inner, h, _, n = _mamba2_sizes(p)
 
-    zxbc = jnp.einsum("bsd,de->bse", x, p["w_in"])
+    zxbc = jnp.einsum("bsd,ed->bse", x, p["w_in"])
     z = zxbc[..., :d_inner]
     xbc = zxbc[..., d_inner : d_inner + d_inner + 2 * n]
     dt = zxbc[..., -h:]
@@ -74,7 +84,11 @@ def _mamba2_project(p, x, conv_state=None):
     else:
         pad = conv_state
     xbc_pad = jnp.concatenate([pad, xbc], axis=1)
-    new_conv_state = xbc_pad[:, -(w - 1):, :]
+    if n_valid is None:
+        new_conv_state = xbc_pad[:, -(w - 1):, :]
+    else:
+        at = n_valid[:, None] + jnp.arange(w - 1)
+        new_conv_state = jnp.take_along_axis(xbc_pad, at[..., None], axis=1)
     # causal depthwise conv via stacked shifts (w is small, 4)
     conv = sum(
         xbc_pad[:, i : i + xbc.shape[1], :] * p["conv_w"][i]
@@ -89,9 +103,8 @@ def _mamba2_project(p, x, conv_state=None):
 
 def mamba2_forward(p, x, state=None, conv_state=None):
     """Full-sequence form. x: (B,S,D) -> (y, (ssm_state, conv_state))."""
-    b, s, d_model = x.shape
-    h = p["a_log"].shape[0]
-    pdim = (2 * d_model) // h
+    b, s, _ = x.shape
+    _, h, pdim, _ = _mamba2_sizes(p)
 
     z, xs, bv, cv, dt, new_conv = _mamba2_project(p, x, conv_state)
     xs = xs.reshape(b, s, h, pdim)
@@ -119,19 +132,25 @@ def mamba2_forward(p, x, state=None, conv_state=None):
     dec_t = jnp.moveaxis(decay, 1, 0)
     state, ys = jax.lax.scan(step, state, (xs_t, bv_t, cv_t, dt_t, dec_t))
     y = jnp.moveaxis(ys, 0, 1)                               # (B,S,H,P)
+    return _mamba2_out(p, x, y, xs, z), (state, new_conv)
+
+
+def _mamba2_out(p, x, y, xs, z):
+    """D skip, gated RMSNorm (Mamba2 style) and the output projection of
+    the scan's ``y`` (B,S,H,P)."""
+    b, s = y.shape[:2]
     y = y + xs.astype(jnp.float32) * p["d_skip"][..., None]
-    y = y.reshape(b, s, 2 * d_model).astype(x.dtype)
-    # gated RMSNorm (Mamba2 style)
+    y = y.reshape(b, s, -1).astype(x.dtype)
     y32 = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
     y32 = y32 * jax.lax.rsqrt(
         jnp.mean(y32 * y32, axis=-1, keepdims=True) + 1e-5
     ) * p["norm_w"]
     out = jnp.einsum("bse,ed->bsd", y32.astype(x.dtype), p["w_out"])
-    return shard(out, "batch", "seq", "embed"), (state, new_conv)
+    return shard(out, "batch", "seq", "embed")
 
 
 def mamba2_forward_chunked(p, x, state=None, conv_state=None,
-                           chunk: int = 512):
+                           chunk: int = 512, n_valid=None):
     """Chunkwise SSD form (Mamba2 paper §6): O(L*chunk) memory, quadratic
     only within a chunk, exact same math as the per-step recurrence.
 
@@ -145,14 +164,22 @@ def mamba2_forward_chunked(p, x, state=None, conv_state=None,
     decode path; backward through THIS form only stores per-chunk boundary
     states (the BPTT residuals of the step form — one (B,H,P,N) state per
     token — cannot fit HBM at 4k).
-    """
-    b, s, d_model = x.shape
-    h = p["a_log"].shape[0]
-    pdim = (2 * d_model) // h
 
-    z, xs, bv, cv, dt, new_conv = _mamba2_project(p, x, conv_state)
+    ``n_valid`` (B,): a padded batch, row b holding ``n_valid[b]`` real
+    positions.  A padded position gets dt = 0, so its decay is 1 and its
+    update 0, and the state out is exactly the state at ``n_valid``; the
+    conv state out is each row's last W-1 real inputs.  The chunk is the
+    largest divisor of S not above ``chunk``.
+    """
+    b, s, _ = x.shape
+    _, h, pdim, _ = _mamba2_sizes(p)
+
+    z, xs, bv, cv, dt, new_conv = _mamba2_project(p, x, conv_state, n_valid)
     xs = xs.reshape(b, s, h, pdim)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])   # (B,S,H)
+    if n_valid is not None:
+        real = jnp.arange(s)[None, :] < n_valid[:, None]
+        dt = jnp.where(real[..., None], dt, 0.0)
     a = -jnp.exp(p["a_log"])
     log_lam = a * dt                                              # (B,S,H) <=0
 
@@ -160,10 +187,10 @@ def mamba2_forward_chunked(p, x, state=None, conv_state=None,
     if state is None:
         state = jnp.zeros((b, h, pdim, n), jnp.float32)
 
-    c = s // max(1, s // min(chunk, s))
-    while s % c:
-        c += 1
-    nc = s // c
+    nc = -(-s // chunk)
+    while s % nc:
+        nc += 1
+    c = s // nc
 
     u = (xs.astype(jnp.float32) * dt[..., None])                  # (B,S,H,P)
     ug = jnp.moveaxis(u.reshape(b, nc, c, h, pdim), 1, 0)
@@ -194,30 +221,12 @@ def mamba2_forward_chunked(p, x, state=None, conv_state=None,
 
     state, ys = jax.lax.scan(one_chunk, state, (ug, bg, cg, lg))
     y = jnp.moveaxis(ys, 0, 1).reshape(b, s, h, pdim)
-    y = y + xs.astype(jnp.float32) * p["d_skip"][..., None]
-    y = y.reshape(b, s, 2 * d_model).astype(x.dtype)
-    y32 = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
-    y32 = y32 * jax.lax.rsqrt(
-        jnp.mean(y32 * y32, axis=-1, keepdims=True) + 1e-5
-    ) * p["norm_w"]
-    out = jnp.einsum("bse,ed->bsd", y32.astype(x.dtype), p["w_out"])
-    return shard(out, "batch", "seq", "embed"), (state, new_conv)
+    return _mamba2_out(p, x, y, xs, z), (state, new_conv)
 
 
 def mamba2_decode(p, x1, state, conv_state):
     """One-token decode. x1: (B,1,D)."""
     return mamba2_forward(p, x1, state=state, conv_state=conv_state)
-
-
-def mamba2_init_state(p, batch: int, d_model: int):
-    h = p["a_log"].shape[0]
-    pdim = (2 * d_model) // h
-    n = (p["w_in"].shape[1] - 4 * d_model - h) // 2
-    w = p["conv_w"].shape[0]
-    return (
-        jnp.zeros((batch, h, pdim, n), jnp.float32),
-        jnp.zeros((batch, w - 1, 2 * d_model + 2 * n), p["conv_w"].dtype),
-    )
 
 
 # -------------------------------------------------------------------- mlstm

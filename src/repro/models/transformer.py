@@ -1,6 +1,7 @@
 """Unified model zoo: one functional Model class covering all assigned
 architecture families (dense GQA / SWA, MoE, VLM decoder, audio enc-dec,
-xLSTM, Mamba2+shared-attention hybrid).
+xLSTM, Mamba2+shared-attention hybrid, Mamba2 and attention layers at
+fixed positions with per-layer MLPs).
 
 Design choices for multi-pod dry-run sanity:
   * layers are STACKED and iterated with jax.lax.scan — the HLO contains one
@@ -8,6 +9,9 @@ Design choices for multi-pod dry-run sanity:
   * caches carry an explicit per-slot position tensor ``kv_pos`` (B, T);
     full caches and SWA ring buffers share one attention masking rule
     (valid = kv_pos >= 0, causal = kv_pos <= q_pos, window optional);
+  * every cache leaf holds the layer axis first and the batch axis second
+    (``(L, B, ...)``), recurrent state as well as K/V, so the engine's
+    slot writes, gathers and swaps index ``[:, slot]`` for every kind;
   * every architecture exposes the same three entry points:
       forward(params, batch)           -> logits            (training)
       prefill(params, batch, cache_len)-> (logits, cache)   (serving)
@@ -114,8 +118,47 @@ def decode_attention(p_attn, h, pos, layer, k_cache, v_cache, kv_pos,
         q_positions=pos[:, None],
         kv_positions=kp,
         kv_valid=kp >= 0,
+        scale=cfg.qk_scale,
     )
     return attention_out(p_attn, att), k_cache, v_cache, kv_pos
+
+
+#: the SSD chunk of the ``mamba2_hybrid`` stack's chunked Mamba2 scan
+#: (granite-4.0-h's ``mamba_chunk_size``); it tiles the scan and leaves
+#: its result as it is
+SSD_CHUNK = 256
+
+
+def layer_pattern(layer_types) -> tuple[int, tuple]:
+    """The period of a per-layer mixer list, and one period's runs of like
+    layers: ``((kind, count, first layer, first layer of that kind), ...)``
+    with both offsets counted from the period's start."""
+    n = len(layer_types)
+    period = next(p for p in range(1, n + 1) if n % p == 0 and all(
+        layer_types[i] == layer_types[i % p] for i in range(n)))
+    runs, seen = [], {}
+    for i, kind in enumerate(layer_types[:period]):
+        if runs and runs[-1][0] == kind:
+            runs[-1] = (kind, runs[-1][1] + 1) + runs[-1][2:]
+        else:
+            runs.append((kind, 1, i, seen.get(kind, 0)))
+        seen[kind] = seen.get(kind, 0) + 1
+    return period, tuple(runs)
+
+
+def _put(stack, row, i):
+    """``stack`` with row ``i`` (traced) replaced: a dynamic-update-slice,
+    which XLA applies in place to a carried buffer."""
+    return jax.lax.dynamic_update_index_in_dim(
+        stack, row.astype(stack.dtype), i, 0
+    )
+
+
+def _take(tree, i):
+    """Row ``i`` (traced) of every leaf of a layer-stacked tree."""
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False), tree
+    )
 
 
 # ===========================================================================
@@ -155,6 +198,7 @@ def dense_block_train(p, x, positions, cfg: ModelConfig, attn_mask_lens=None):
         q_positions=positions,
         kv_positions=positions,
         kv_valid=kv_valid,
+        scale=cfg.qk_scale,
     )
     x = x + attention_out(p["attn"], att)
     h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
@@ -180,12 +224,30 @@ def dense_block_chunk(p, x, pos, positions, lens, k_cache, v_cache, kv_pos,
     slots and padded tails, so the result matches full-sequence prefill.
     """
     h = rms_norm(x, p["ln1"], cfg.norm_eps)
+    att, k_cache, v_cache, kv_pos = attention_chunk(
+        p["attn"], h, pos, positions, lens, k_cache, v_cache, kv_pos, cfg
+    )
+    x = x + att
+    h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
+    if cfg.is_moe:
+        y, _ = moe_mlp(p["moe"], h2, top_k=cfg.top_k,
+                       capacity_factor=cfg.capacity_factor)
+    else:
+        y = gated_mlp(p["mlp"], h2)
+    return x + y, k_cache, v_cache, kv_pos
+
+
+def attention_chunk(p_attn, h, pos, positions, lens, k_cache, v_cache,
+                    kv_pos, cfg: ModelConfig):
+    """The self-attention of ``dense_block_chunk`` on the normed input
+    ``h``: the chunk's K/V and lens-masked positions are written into the
+    layer's cache, then its queries attend over the whole cache."""
     q, k_new, v_new = attention_qkv(
-        p["attn"], h, positions, cfg.rope_theta, cfg.use_rope
+        p_attn, h, positions, cfg.rope_theta, cfg.use_rope
     )
     k_cache = update_cache(k_cache, kv_rows(k_new), pos)
     v_cache = update_cache(v_cache, kv_rows(v_new), pos)
-    kv_pos = update_pos_masked(kv_pos, pos, x.shape[1], lens)
+    kv_pos = update_pos_masked(kv_pos, pos, h.shape[1], lens)
     att = attention_any(
         q,
         kv_heads(k_cache, cfg.n_kv_heads),
@@ -194,15 +256,9 @@ def dense_block_chunk(p, x, pos, positions, lens, k_cache, v_cache, kv_pos,
         q_positions=positions,
         kv_positions=kv_pos,
         kv_valid=kv_pos >= 0,
+        scale=cfg.qk_scale,
     )
-    x = x + attention_out(p["attn"], att)
-    h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
-    if cfg.is_moe:
-        y, _ = moe_mlp(p["moe"], h2, top_k=cfg.top_k,
-                       capacity_factor=cfg.capacity_factor)
-    else:
-        y = gated_mlp(p["mlp"], h2)
-    return x + y, k_cache, v_cache, kv_pos
+    return attention_out(p_attn, att), k_cache, v_cache, kv_pos
 
 
 def dense_block_decode(p, x, pos, layer, k_cache, v_cache, kv_pos,
@@ -232,6 +288,18 @@ def dense_block_decode(p, x, pos, layer, k_cache, v_cache, kv_pos,
 class Model:
     cfg: ModelConfig
 
+    @property
+    def ragged_prefill(self) -> bool:
+        """``prefill_chunked`` takes a batch of prompts padded to one
+        length, with ``lens``, and gives each row exactly what it gives the
+        prompt alone (the engine then batches and buckets prefills)."""
+        return self.cfg.kind in ("dense", "moe", "vlm", "mamba2_hybrid")
+
+    @property
+    def recurrent_state(self) -> bool:
+        """The cache holds per-sequence recurrent state besides K/V."""
+        return self.cfg.kind in ("ssm", "hybrid", "mamba2_hybrid")
+
     # ------------------------------------------------------------ init
 
     def init(self, key) -> dict:
@@ -248,7 +316,7 @@ class Model:
                 jax.random.normal(keys[1], (cfg.d_model, cfg.vocab))
                 * cfg.d_model ** -0.5
             ).astype(dtype)
-        if not cfg.use_rope:
+        if cfg.position == "learned":
             params["pos_emb"] = (
                 jax.random.normal(keys[2], (cfg.max_position, cfg.d_model))
                 * 0.02
@@ -287,6 +355,21 @@ class Model:
             )(mkeys)
             # zamba2's single SHARED attention+MLP block
             params["shared_attn"] = init_dense_block(keys[4], cfg, dtype)
+        elif cfg.kind == "mamba2_hybrid":
+            n_mamba = cfg.layer_types.count("mamba")
+            n_attn = cfg.layer_types.count("attention")
+            dims = AttnDims(cfg.n_heads, cfg.n_kv_heads, cfg.head_dim)
+            params["layers"] = jax.vmap(lambda k: {
+                "ln1": jnp.ones((cfg.d_model,), jnp.float32),
+                "ln2": jnp.ones((cfg.d_model,), jnp.float32),
+                "mlp": init_mlp(k, cfg.d_model, cfg.d_ff, dtype),
+            })(jax.random.split(keys[3], cfg.n_layers))
+            params["mamba"] = jax.vmap(lambda k: ssm.init_mamba2(
+                k, cfg.d_model, cfg.ssm_state, cfg.conv_width, dtype
+            ))(jax.random.split(keys[4], n_mamba))
+            params["attn"] = jax.vmap(
+                lambda k: init_attention(k, cfg.d_model, dims, dtype)
+            )(jax.random.split(keys[5], n_attn))
         else:
             raise ValueError(f"unknown kind {cfg.kind}")
         return params
@@ -332,8 +415,10 @@ class Model:
     def _embed(self, params, tokens, positions):
         cfg = self.cfg
         x = jnp.take(params["embed"], tokens, axis=0)
-        if not cfg.use_rope:
+        if cfg.position == "learned":
             x = x + jnp.take(params["pos_emb"], positions, axis=0)
+        if cfg.embedding_multiplier != 1.0:
+            x = x * cfg.embedding_multiplier
         return shard(x, "batch", "seq", "embed")
 
     def head_matrix(self, params):
@@ -345,7 +430,12 @@ class Model:
     def _logits(self, params, x):
         cfg = self.cfg
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        return self._head(params, x)
+
+    def _head(self, params, x):
         logits = jnp.einsum("bsd,dv->bsv", x, self.head_matrix(params))
+        if self.cfg.logits_scaling != 1.0:
+            logits = logits / self.cfg.logits_scaling
         return shard(logits, "batch", "seq", "vocab")
 
     # ------------------------------------------------------------ train
@@ -390,8 +480,7 @@ class Model:
     def forward(self, params, batch: dict) -> tuple[jnp.ndarray, jnp.ndarray]:
         """Training forward returning full logits (small configs only)."""
         x, aux = self.hidden(params, batch)
-        logits = jnp.einsum("bsd,dv->bsv", x, self.head_matrix(params))
-        return shard(logits, "batch", "seq", "vocab"), aux
+        return self._head(params, x), aux
 
     def _run_stack_train(self, params, x, positions, remat: bool = True):
         cfg = self.cfg
@@ -441,7 +530,71 @@ class Model:
                 body = jax.checkpoint(body)
             x, _ = jax.lax.scan(body, x, params["super_blocks"])
             return x, jnp.float32(0.0)
+        if cfg.kind == "mamba2_hybrid":
+            def mixer(kind, w, h, idx, carry):
+                if kind == "mamba":
+                    y, _ = ssm.mamba2_forward_chunked(w, h, chunk=SSD_CHUNK)
+                    return y, carry
+                q, k, v = attention_qkv(w, h, positions, cfg.rope_theta,
+                                        cfg.use_rope)
+                att = attention_any(q, k, v, q_positions=positions,
+                                    kv_positions=positions,
+                                    scale=cfg.qk_scale)
+                return attention_out(w, att), carry
+
+            x, _ = self._hybrid_stack(params, x, None, mixer, remat=remat)
+            return x, jnp.float32(0.0)
         raise ValueError(cfg.kind)
+
+    def _hybrid_stack(self, params, x, carry, mixer, remat: bool = False):
+        """The ``mamba2_hybrid`` layer stack: a scan over the periods of
+        ``layer_types``, and inside one period a scan over each run of like
+        layers, so the program holds one body per run, not one per layer.
+        Each layer, with its own weights:
+
+            x = x + r * mixer(rmsnorm(x) * ln1)      Mamba2 or attention
+            x = x + r * mlp(rmsnorm(x) * ln2)        SwiGLU
+
+        with r the residual multiplier.  ``mixer(kind, weights, h, index,
+        carry) -> (y, carry)`` receives the layer's index among the layers
+        of its kind: its row of ``params["mamba"]`` or ``params["attn"]``,
+        and of the cache leaves the carry holds."""
+        cfg = self.cfg
+        period, runs = layer_pattern(cfg.layer_types)
+        per = {k: cfg.layer_types[:period].count(k)
+               for k in ("mamba", "attention")}
+        weights = {"mamba": params.get("mamba"),
+                   "attention": params.get("attn")}
+        scope = {"mamba": "mamba2", "attention": "attention"}
+        r = cfg.residual_multiplier
+
+        def period_body(c, p):
+            for kind, n, l0, k0 in runs:
+                def layer(c, i, kind=kind, l0=l0, k0=k0):
+                    x, carry = c
+                    lp = _take(params["layers"], p * period + l0 + i)
+                    idx = p * per[kind] + k0 + i
+                    with jax.named_scope(scope[kind]):
+                        y, carry = mixer(
+                            kind, _take(weights[kind], idx),
+                            rms_norm(x, lp["ln1"], cfg.norm_eps), idx, carry,
+                        )
+                    x = x + y * r
+                    with jax.named_scope("mlp"):
+                        y = gated_mlp(lp["mlp"],
+                                      rms_norm(x, lp["ln2"], cfg.norm_eps))
+                    return (x + y * r, carry), None
+
+                if remat:
+                    layer = jax.checkpoint(layer)
+                c, _ = jax.lax.scan(layer, c, jnp.arange(n))
+            return c, None
+
+        (x, carry), _ = jax.lax.scan(
+            period_body, (x, carry),
+            jnp.arange(len(cfg.layer_types) // period),
+        )
+        return x, carry
 
     def _run_encoder(self, params, enc):
         cfg = self.cfg
@@ -529,19 +682,23 @@ class Model:
                 "slstm_m": jnp.full((n_pairs, batch, nh, hd), -1e30,
                                     jnp.float32),
             }
-        if cfg.kind == "hybrid":
-            n_super = cfg.n_layers // cfg.attn_every
+        if cfg.kind in ("hybrid", "mamba2_hybrid"):
+            # one row of state per Mamba2 layer, one of K/V per attention
+            if cfg.kind == "hybrid":
+                n_mamba = n_attn = cfg.n_layers // cfg.attn_every
+                n_mamba *= cfg.attn_every
+            else:
+                n_mamba = cfg.layer_types.count("mamba")
+                n_attn = cfg.layer_types.count("attention")
             d_inner, pdim, h, n = ssm.mamba2_dims(cfg.d_model, cfg.ssm_state)
-            w = cfg.conv_width
             return {
-                "mamba_h": jnp.zeros(
-                    (n_super, cfg.attn_every, batch, h, pdim, n), jnp.float32
-                ),
+                "mamba_h": jnp.zeros((n_mamba, batch, h, pdim, n),
+                                     jnp.float32),
                 "mamba_conv": jnp.zeros(
-                    (n_super, cfg.attn_every, batch, w - 1, d_inner + 2 * n),
-                    _dtype(cfg),
+                    (n_mamba, batch, cfg.conv_width - 1, d_inner + 2 * n),
+                    dtype,
                 ),
-                "k": kv(n_super), "v": kv(n_super), "kv_pos": pos(n_super),
+                "k": kv(n_attn), "v": kv(n_attn), "kv_pos": pos(n_attn),
             }
         raise ValueError(cfg.kind)
 
@@ -660,9 +817,15 @@ class Model:
             x, (m_states, ks, vs) = jax.lax.scan(body, x,
                                                  params["super_blocks"])
             cache = self._fill_kv(cache, ks, vs, lens, s)
-            cache["mamba_h"], cache["mamba_conv"] = m_states
+            # (n_super, attn_every, B, ...) -> the cache's (layer, B, ...)
+            cache["mamba_h"], cache["mamba_conv"] = (
+                st.reshape(-1, *st.shape[2:]) for st in m_states
+            )
             logits = self._logits(params, _gather_last(x, lens))
             return logits, cache
+
+        if cfg.kind == "mamba2_hybrid":
+            return self._hybrid_prefill(params, tokens, lens, cache_len, s)
 
         raise ValueError(cfg.kind)
 
@@ -677,16 +840,26 @@ class Model:
         *actually* processed in chunk-sized slices rather than merely
         accounted as multiple iterations.
 
+        ``mamba2_hybrid`` carries its SSM and conv state from slice to
+        slice, and a padded position leaves a row's state as it was
+        (``ssm.mamba2_forward_chunked``'s ``n_valid``), so a padded batch
+        with ``lens`` prefills every row exactly.
+
         Falls back to the one-shot :meth:`prefill` when chunking cannot
         help or would change the result: prompts that fit in one chunk,
-        non-attention-cache families (recurrent state would need chunk
-        carry), MoE (GShard capacity routing is sequence-length dependent,
+        the other recurrent families (ssm, the shared-block hybrid),
+        encdec, MoE (GShard capacity routing is sequence-length dependent,
         so per-chunk capacities drop different tokens than one-shot),
         VLM image batches, and ring (sliding-window) caches smaller than
         the prompt.
         """
         cfg = self.cfg
         s = batch["tokens"].shape[1]
+        if cfg.kind == "mamba2_hybrid":
+            b = batch["tokens"].shape[0]
+            lens = batch.get("lens", jnp.full((b,), s, jnp.int32))
+            return self._hybrid_prefill(params, batch["tokens"], lens,
+                                        cache_len, chunk)
         ring = bool(cfg.sliding_window) and min(
             cache_len, cfg.sliding_window
         ) < cache_len
@@ -729,6 +902,59 @@ class Model:
         cache = dict(cache, k=k_cache, v=v_cache, kv_pos=kv_pos)
         logits = self._logits(params, _gather_last(x, lens))
         return logits, cache
+
+    def _hybrid_prefill(self, params, tokens, lens, cache_len: int,
+                        chunk: int):
+        """``mamba2_hybrid`` prefill of a padded batch (B,S) with true
+        lengths ``lens``, ``chunk`` positions at a time: each slice runs the
+        whole stack, its Mamba2 layers starting from the state and conv
+        inputs the previous slice left, its attention layers writing their
+        lens-masked K/V (``attention_chunk``)."""
+        cfg = self.cfg
+        b, s = tokens.shape
+        # K/V rows for the prompt's own S positions, padded to cache_len at
+        # the end: no query attends past the prompt
+        cache = self.init_cache(params, b, s)
+        carry = tuple(cache[k] for k in ("mamba_h", "mamba_conv", "k", "v",
+                                         "kv_pos"))
+        hidden = []
+        for c0 in range(0, s, chunk):
+            toks_c = tokens[:, c0:c0 + chunk]
+            sc = toks_c.shape[1]
+            positions = jnp.broadcast_to(
+                jnp.arange(c0, c0 + sc)[None], (b, sc)
+            )
+            pos0 = jnp.full((b,), c0, jnp.int32)
+            n_valid = jnp.clip(lens - c0, 0, sc)
+
+            def mixer(kind, w, h, idx, carry, positions=positions,
+                      pos0=pos0, n_valid=n_valid):
+                mh, mc, kc, vc, kp = carry
+                if kind == "mamba":
+                    y, (st, cv) = ssm.mamba2_forward_chunked(
+                        w, h, mh[idx], mc[idx], chunk=SSD_CHUNK,
+                        n_valid=n_valid,
+                    )
+                    return y, (_put(mh, st, idx), _put(mc, cv, idx),
+                               kc, vc, kp)
+                y, k1, v1, p1 = attention_chunk(
+                    w, h, pos0, positions, lens, kc[idx], vc[idx], kp[idx],
+                    cfg,
+                )
+                return y, (mh, mc, _put(kc, k1, idx), _put(vc, v1, idx),
+                           _put(kp, p1, idx))
+
+            x = self._embed(params, toks_c, positions)
+            x, carry = self._hybrid_stack(params, x, carry, mixer)
+            hidden.append(x)
+        x = jnp.concatenate(hidden, axis=1)
+        cache = dict(zip(("mamba_h", "mamba_conv", "k", "v", "kv_pos"),
+                         carry))
+        pad = [(0, 0), (0, 0), (0, cache_len - s)]
+        cache["k"] = jnp.pad(cache["k"], pad + [(0, 0)])
+        cache["v"] = jnp.pad(cache["v"], pad + [(0, 0)])
+        cache["kv_pos"] = jnp.pad(cache["kv_pos"], pad, constant_values=-1)
+        return self._logits(params, _gather_last(x, lens)), cache
 
     def prefill_slice(self, params, cache: dict, tokens, slot, start, total):
         """One bounded prefill slice of a SINGLE batch slot against a live
@@ -787,6 +1013,7 @@ class Model:
                 q_positions=positions,
                 kv_positions=kp_row,
                 kv_valid=kp_row >= 0,
+                scale=cfg.qk_scale,
             )
             h = h + attention_out(lp["attn"], att)
             h2 = rms_norm(h, lp["ln2"], cfg.norm_eps)
@@ -842,6 +1069,8 @@ class Model:
         cfg = self.cfg
         b = tokens.shape[0]
         x = self._embed(params, tokens, pos[:, None])
+        if cfg.kind == "mamba2_hybrid":
+            return self._hybrid_decode(params, cache, x, pos)
         ring = bool(cfg.sliding_window) and (
             "k" in cache and cache["k"].shape[2] == cfg.sliding_window
         )
@@ -942,16 +1171,43 @@ class Model:
                                                    vc, kp, cfg, ring)
                 return (h, kc, vc, kp), (mh, mconv)
 
+            # the cache's (layer, B, ...) state as (n_super, attn_every,
+            # B, ...) for the two-level scan, and back
+            group = lambda st: st.reshape(-1, cfg.attn_every, *st.shape[1:])
             (x, ks, vs, kps), (mh, mconv) = jax.lax.scan(
                 body, init,
-                (params["super_blocks"], cache["mamba_h"],
-                 cache["mamba_conv"], layers),
+                (params["super_blocks"], group(cache["mamba_h"]),
+                 group(cache["mamba_conv"]), layers),
             )
-            cache = dict(cache, mamba_h=mh, mamba_conv=mconv, k=ks, v=vs,
-                         kv_pos=kps)
+            cache = dict(cache, mamba_h=mh.reshape(cache["mamba_h"].shape),
+                         mamba_conv=mconv.reshape(cache["mamba_conv"].shape),
+                         k=ks, v=vs, kv_pos=kps)
             return self._logits(params, x), cache
 
         raise ValueError(cfg.kind)
+
+    def _hybrid_decode(self, params, cache: dict, x, pos):
+        """One ``mamba2_hybrid`` decode step.  Every cache leaf rides the
+        layer scans' carry: a Mamba2 layer reads its row of ``mamba_h`` and
+        ``mamba_conv`` and writes the new state back in place, an attention
+        layer writes its B new K/V rows (``decode_attention``), so a window
+        that scans this step and donates the cache holds one copy of it."""
+        cfg = self.cfg
+
+        def mixer(kind, w, h, idx, carry):
+            mh, mc, kc, vc, kp = carry
+            if kind == "mamba":
+                y, (st, cv) = ssm.mamba2_decode(w, h, mh[idx], mc[idx])
+                return y, (_put(mh, st, idx), _put(mc, cv, idx), kc, vc, kp)
+            y, kc, vc, kp = decode_attention(w, h, pos, idx, kc, vc, kp, cfg,
+                                             ring=False)
+            return y, (mh, mc, kc, vc, kp)
+
+        names = ("mamba_h", "mamba_conv", "k", "v", "kv_pos")
+        x, carry = self._hybrid_stack(
+            params, x, tuple(cache[k] for k in names), mixer
+        )
+        return self._logits(params, x), dict(cache, **dict(zip(names, carry)))
 
 
 def _gather_last(x, lens):

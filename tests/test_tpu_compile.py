@@ -3,13 +3,15 @@
 Compiles ``repro.engine.engine``'s jitted hot path for one chip of a
 described (not attached) ``v5e:2x2`` topology, at granite-3-2b's published
 widths (40 layers, d_model 2048, vocab 49155, bf16) and the one-chip sizes
-``chip_smoke.py`` serves at (``V5E_ENGINE_KW``).  Each program must compile,
-and its argument bytes plus temporaries must stay under the 15.75 GiB of
-HBM the compiler allows on a v5e.  The decode windows, at granite's and
-h2o-danube-1.8b's widths, must also update the one donated KV cache in
-place: temporaries under a quarter of the cache and no copy of the whole
-stacked K or V.  Nothing runs, so no result or time is checked here;
-parameters and cache are ``ShapeDtypeStruct``s from ``jax.eval_shape``.
+``chip_smoke.py`` serves at (``V5E_ENGINE_KW``), and at granite-4.0-h-micro's
+(36 Mamba-2 and 4 attention layers, vocab 100352) with its benchmark's 16
+slots.  Each program must compile, and its argument bytes plus temporaries
+must stay under the 15.75 GiB of HBM the compiler allows on a v5e.  The
+decode windows, at granite's, h2o-danube-1.8b's and granite-4.0-h-micro's
+widths, must also update the one donated cache in place: temporaries under
+a quarter of the cache and no copy of the whole stacked K, V or Mamba-2
+state.  Nothing runs, so no result or time is checked here; parameters and
+cache are ``ShapeDtypeStruct``s from ``jax.eval_shape``.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU compiler's library.
@@ -31,6 +33,7 @@ from repro.configs import get_config
 from repro.engine import ServeEngine
 from repro.engine import engine as E
 from repro.models import Model
+from repro.models import ssm
 
 #: the HBM a v5e's compiler lets one program use
 V5E_HBM = 15.75 * 2**30
@@ -39,6 +42,11 @@ PROMPT_BUCKET = 512
 ENGINE_DEFAULTS = inspect.signature(ServeEngine).parameters
 MAX_WINDOW = ENGINE_DEFAULTS["max_window"].default
 PREFILL_CHUNK = ENGINE_DEFAULTS["prefill_chunk"].default
+#: the hybrid's one-chip engine (``bench/configs/granite-4.0-h-micro.json``)
+#: and the largest prompt bucket its traffic draws, prefilled in two slices
+HYBRID = "granite-4.0-h-micro"
+HYBRID_KW = {"max_batch": 16, "cache_len": 2048}
+HYBRID_BUCKET = 768
 
 
 @pytest.fixture(scope="module")
@@ -64,7 +72,6 @@ def one_chip():
 def shapes_of(one_chip):
     """``shapes_of(arch)``: the model and the argument shapes of its
     engine programs, made once per architecture."""
-    b, t = V5E_ENGINE_KW["max_batch"], V5E_ENGINE_KW["cache_len"]
 
     def sds(tree):
         return jax.tree.map(
@@ -79,6 +86,8 @@ def shapes_of(one_chip):
     made = {}
 
     def make(arch):
+        kw = HYBRID_KW if arch == HYBRID else V5E_ENGINE_KW
+        b, t = kw["max_batch"], kw["cache_len"]
         if arch not in made:
             model = Model(get_config(arch))
             params = sds(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
@@ -96,8 +105,8 @@ def shapes(shapes_of):
     return shapes_of("granite-3-2b")
 
 
-def lowerings(model, params, cache, row, i32):
-    b, t = V5E_ENGINE_KW["max_batch"], V5E_ENGINE_KW["cache_len"]
+def lowerings(model, params, cache, row, i32, bucket=PROMPT_BUCKET):
+    b, t = cache["kv_pos"].shape[1:]
     k, chunk = MAX_WINDOW, PREFILL_CHUNK
     state = i32(3, b)
     # _prefill_batch pads a pass to the power-of-two ceiling of max_batch
@@ -111,7 +120,7 @@ def lowerings(model, params, cache, row, i32):
             model, k, chunk, params, cache, state, i32(k, chunk), i32(3)),
         "prefill_write": lambda: E._prefill_write_jit.lower(
             model, t, chunk, params, cache,
-            i32(pad, PROMPT_BUCKET), i32(pad), i32(pad)),
+            i32(pad, bucket), i32(pad), i32(pad)),
         "gather_slot": lambda: E._gather_slot_jit.lower(cache, i32()),
         "scatter_slot": lambda: E._scatter_slot_jit.lower(cache, row, i32()),
     }
@@ -159,3 +168,50 @@ def test_decode_window_updates_one_cache_in_place(shapes_of, arch, program):
     copies = re.findall(rf"= \(?\w+\[{dims}\][^=]* copy(?:-start)?\(",
                         compiled.as_text())
     assert not copies, f"{arch} {program}: whole-cache copies {copies}"
+
+
+def test_hybrid_widths_are_published():
+    cfg = get_config(HYBRID)
+    assert (cfg.n_layers, cfg.d_model, cfg.vocab, cfg.dtype) == (
+        40, 2048, 100352, "bfloat16"
+    )
+    assert cfg.layer_types.count("attention") == 4
+    assert ssm.mamba2_dims(cfg.d_model, cfg.ssm_state) == (4096, 64, 64, 128)
+
+
+@pytest.mark.parametrize("program", [
+    "decode_window_1", "decode_window_max", "prefill_write", "gather_slot",
+    "scatter_slot",
+])
+def test_hybrid_program_fits_one_v5e(shapes_of, program):
+    compiled = lowerings(*shapes_of(HYBRID), bucket=HYBRID_BUCKET)[
+        program]().compile()
+    mem = compiled.memory_analysis()
+    used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert used < V5E_HBM, (
+        f"{program}: {used / 2**30:.2f} GiB of arguments + temporaries "
+        f"exceeds the v5e's {V5E_HBM / 2**30} GiB"
+    )
+
+
+@pytest.mark.parametrize("program", ["decode_window_1", "decode_window_max"])
+def test_hybrid_decode_window_updates_its_state_in_place(shapes_of, program):
+    """The window carries K/V and the Mamba-2 state through its scans: no
+    temporary near the size of the cache plus state, and no copy of the
+    whole ``mamba_h``, ``k`` or ``v`` stack."""
+    shapes = shapes_of(HYBRID)
+    cache = shapes[2]
+    compiled = lowerings(*shapes)[program]().compile()
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree.leaves(cache))
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < cache_bytes / 4, (
+        f"{program}: {temp / 2**30:.2f} GiB of temporaries against "
+        f"{cache_bytes / 2**30:.2f} GiB of cache and state"
+    )
+    text = compiled.as_text()
+    for leaf in ("mamba_h", "k", "v"):
+        dims = ",".join(map(str, cache[leaf].shape))
+        copies = re.findall(rf"= \(?\w+\[{dims}\][^=]* copy(?:-start)?\(",
+                            text)
+        assert not copies, f"{program}: whole-{leaf} copies {copies}"
