@@ -91,8 +91,13 @@ swap-in, ``swap``), the window's ``prep`` (with its swap-outs),
 ``device_wait`` (dispatch to tokens on the host) and the token
 ``replay``.  ``prefill_passes`` counts prefill dispatches,
 ``prefill_tokens`` the prompt positions they prefilled and
-``prefill_rows`` the positions they computed (batch pad x bucket).  A
-few phases open per window and none per token.  Each request carries
+``prefill_rows`` the positions they computed (batch pad x bucket).
+``attn_rows_read`` counts the KV rows one layer's decode attention reads
+over each window's slots and steps (``models.transformer.attn_rows_read``:
+each slot's live blocks where the decode kernel engages, the whole
+reservation where it does not), and ``attn_rows_reserved`` the rows the
+slots hold, ``max_batch x cache_len`` a step.  A few phases open per
+window and none per token.  Each request carries
 host stamps, ``t_queued`` and ``t_admit``, that no scheduling decision
 reads.
 """
@@ -116,6 +121,7 @@ from repro.engine.trace import Phases
 from repro.kvcache.allocator import BlockAllocator
 from repro.kvcache.prefix import PrefixAwareAllocator
 from repro.models import Model
+from repro.models.transformer import attn_rows_read
 
 
 # --------------------------------------------------------------------------
@@ -556,6 +562,7 @@ class ServeEngine:
                         "suspensions": 0, "resumes": 0,
                         "suspend_spills": 0, "prefill_passes": 0,
                         "prefill_tokens": 0, "prefill_rows": 0,
+                        "attn_rows_read": 0, "attn_rows_reserved": 0,
                         # self times of the host phases (seconds)
                         "step_s": 0.0, "admit_s": 0.0, "prefill_s": 0.0,
                         "swap_s": 0.0, "prep_s": 0.0,
@@ -1535,6 +1542,26 @@ class ServeEngine:
             return 1
         return 1 << (cap.bit_length() - 1)   # bucket: bounds compilations
 
+    def _count_attn_rows(self, k: int, snapshot, pf) -> None:
+        """Add a window's decode-attention rows to ``attn_rows_read`` and
+        ``attn_rows_reserved``, from the host's mirror of every slot's
+        position at each of the K steps: a slot advances while its budget
+        lasts and then decodes frozen, and a fused prefill's slot chases
+        its slice starts (``_fused_window_jit``)."""
+        if "kv_pos" not in self.cache:
+            return
+        _, b, t = self.cache["kv_pos"].shape
+        rem = np.zeros(b, np.int64)
+        for slot, req in snapshot:
+            rem[slot] = req.max_new_tokens - req.generated
+        steps = np.arange(k)[:, None]
+        pos = self.slot_pos[None, :] + np.minimum(steps, rem[None, :])
+        if pf is not None:
+            pos[:, pf.slot] = pf.written + steps[:, 0] * self.prefill_chunk
+        self.metrics["attn_rows_read"] += attn_rows_read(
+            self.cache["k"].shape[-1], pos, t)
+        self.metrics["attn_rows_reserved"] += k * b * t
+
     def _decode_once(self, limit: Optional[int] = None) -> int:
         if not self.slot_req and self._pf is None:
             return 1
@@ -1580,6 +1607,7 @@ class ServeEngine:
                                         pf.written + (j + 1) * chunk]
                     sl[j, :len(seg)] = seg
                 meta = np.array([pf.slot, pf.written, pf.total], np.int32)
+            self._count_attn_rows(k, snapshot, pf)
         with self._phase("device_wait", k=k, batch=len(snapshot),
                          fused=pf is not None):
             if pf is not None:

@@ -26,7 +26,9 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from repro.kernels import decode_attention as decode_kernels
 from repro.models import ssm
 from repro.models.config import Block, ModelConfig
 from repro.models.layers import (
@@ -43,7 +45,7 @@ from repro.models.layers import (
     moe_mlp,
     rms_norm,
 )
-from repro.models.shardlib import shard
+from repro.models.shardlib import active_rules, shard
 
 
 def _dtype(cfg: ModelConfig):
@@ -86,6 +88,30 @@ def update_pos_masked(kv_pos, pos, s, lens):
     return jax.vmap(upd)(kv_pos, pos, lens)
 
 
+def decode_kernel(width: int):
+    """The Pallas decode attention (``repro.kernels.decode_attention``) for
+    cache rows ``width`` lanes wide, or None for the XLA path.  It engages
+    on a TPU with no sharding rules installed, for rows a multiple of 128
+    wide; the CPU, the mesh-sharded paths and prefill take
+    ``gqa_attention``."""
+    if (jax.default_backend() == "tpu" and active_rules() is None
+            and width % 128 == 0):
+        return decode_kernels.decode_attention
+    return None
+
+
+def attn_rows_read(width: int, pos, t: int) -> int:
+    """The rows of one layer's K cache that decode attention reads for
+    slots at positions ``pos`` (a host array of any shape) in a cache of
+    ``t`` rows: each slot's live blocks with the kernel, all ``t`` rows on
+    the XLA path."""
+    pos = np.asarray(pos)
+    if decode_kernel(width) is None:
+        return pos.size * t
+    block = decode_kernels.block_rows(t)
+    return int(decode_kernels.live_blocks(pos, t, block, np).sum()) * block
+
+
 def decode_attention(p_attn, h, pos, layer, k_cache, v_cache, kv_pos,
                      cfg: ModelConfig, ring: bool):
     """One decode step's self-attention at ``layer`` of the layer-stacked
@@ -94,9 +120,11 @@ def decode_attention(p_attn, h, pos, layer, k_cache, v_cache, kv_pos,
     ``k_cache``/``v_cache``: (L,B,T,n_kv*head_dim); ``kv_pos``: (L,B,T).
     Each slot's new key, value and position land at ``(layer, slot,
     row)``, where ``row`` is ``pos`` on a full cache and ``pos % T`` on a
-    sliding-window ring; the query then attends over ``layer``'s slice.
-    Only B rows are written, so a layer scan that carries the caches keeps
-    one buffer instead of rebuilding the stack every step.
+    sliding-window ring; the query then attends over ``layer``'s slice,
+    through ``decode_kernel`` where it engages: it reads each slot's rows
+    up to ``pos`` from the whole stacked cache as they lie.  Only B rows
+    are written, so a layer scan that carries the caches keeps one buffer
+    instead of rebuilding the stack every step.
     """
     b, t = kv_pos.shape[1], kv_pos.shape[2]
     q, k_new, v_new = attention_qkv(
@@ -109,6 +137,12 @@ def decode_attention(p_attn, h, pos, layer, k_cache, v_cache, kv_pos,
     v_cache = v_cache.at[at].set(kv_rows(v_new[:, 0]).astype(v_cache.dtype),
                                  mode="clip")
     kv_pos = kv_pos.at[at].set(pos.astype(kv_pos.dtype), mode="clip")
+    kernel = decode_kernel(k_cache.shape[-1])
+    if kernel is not None:
+        att = kernel(q[:, 0], k_cache, v_cache, kv_pos, layer, pos,
+                     scale=cfg.qk_scale, window=cfg.sliding_window)
+        return (attention_out(p_attn, att[:, None]), k_cache, v_cache,
+                kv_pos)
     kp = kv_pos[layer]
     att = gqa_attention(
         q,

@@ -10,8 +10,12 @@ must stay under the 15.75 GiB of HBM the compiler allows on a v5e.  The
 decode windows, at granite's, h2o-danube-1.8b's and granite-4.0-h-micro's
 widths, must also update the one donated cache in place: temporaries under
 a quarter of the cache and no copy of the whole stacked K, V or Mamba-2
-state.  Nothing runs, so no result or time is checked here; parameters and
-cache are ``ShapeDtypeStruct``s from ``jax.eval_shape``.
+state; and their attention must read the cache through the Pallas decode
+kernel (``repro.kernels.decode_attention``), a Mosaic custom call.  The
+kernel engages where ``jax.default_backend()`` is a TPU, which it is not
+here, so the selector is patched for these compiles.  Nothing runs, so no
+result or time is checked here; parameters and cache are
+``ShapeDtypeStruct``s from ``jax.eval_shape``.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU compiler's library.
@@ -32,8 +36,10 @@ from repro.api.workload import V5E_ENGINE_KW
 from repro.configs import get_config
 from repro.engine import ServeEngine
 from repro.engine import engine as E
+from repro.kernels import decode_attention as decode_kernels
 from repro.models import Model
 from repro.models import ssm
+from repro.models import transformer
 
 #: the HBM a v5e's compiler lets one program use
 V5E_HBM = 15.75 * 2**30
@@ -69,7 +75,16 @@ def one_chip():
 
 
 @pytest.fixture(scope="module")
-def shapes_of(one_chip):
+def tpu_decode():
+    """Choose the decode kernel as a TPU process would."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(transformer, "decode_kernel",
+                   lambda width: decode_kernels.decode_attention)
+        yield
+
+
+@pytest.fixture(scope="module")
+def shapes_of(one_chip, tpu_decode):
     """``shapes_of(arch)``: the model and the argument shapes of its
     engine programs, made once per architecture."""
 
@@ -101,8 +116,19 @@ def shapes_of(one_chip):
 
 
 @pytest.fixture(scope="module")
-def shapes(shapes_of):
-    return shapes_of("granite-3-2b")
+def compiled_of(shapes_of):
+    """``compiled_of(arch, program)``: the engine program compiled once
+    per architecture, at the largest prompt bucket its traffic draws."""
+    done = {}
+
+    def compile_(arch, program):
+        if (arch, program) not in done:
+            bucket = HYBRID_BUCKET if arch == HYBRID else PROMPT_BUCKET
+            done[arch, program] = lowerings(
+                *shapes_of(arch), bucket=bucket)[program]().compile()
+        return done[arch, program]
+
+    return compile_
 
 
 def lowerings(model, params, cache, row, i32, bucket=PROMPT_BUCKET):
@@ -138,8 +164,8 @@ def test_granite_widths_are_published():
     "decode_window_1", "decode_window_max", "fused_window_max",
     "prefill_write", "gather_slot", "scatter_slot",
 ])
-def test_engine_program_fits_one_v5e(shapes, program):
-    compiled = lowerings(*shapes)[program]().compile()
+def test_engine_program_fits_one_v5e(compiled_of, program):
+    compiled = compiled_of("granite-3-2b", program)
     mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert used < V5E_HBM, (
@@ -152,10 +178,10 @@ def test_engine_program_fits_one_v5e(shapes, program):
     "decode_window_1", "decode_window_max", "fused_window_max",
 ])
 @pytest.mark.parametrize("arch", ["granite-3-2b", "h2o-danube-1.8b"])
-def test_decode_window_updates_one_cache_in_place(shapes_of, arch, program):
-    shapes = shapes_of(arch)
-    cache = shapes[2]
-    compiled = lowerings(*shapes)[program]().compile()
+def test_decode_window_updates_one_cache_in_place(shapes_of, compiled_of,
+                                                  arch, program):
+    cache = shapes_of(arch)[2]
+    compiled = compiled_of(arch, program)
     cache_bytes = sum(a.size * a.dtype.itemsize
                       for a in jax.tree.leaves(cache))
     temp = compiled.memory_analysis().temp_size_in_bytes
@@ -183,9 +209,8 @@ def test_hybrid_widths_are_published():
     "decode_window_1", "decode_window_max", "prefill_write", "gather_slot",
     "scatter_slot",
 ])
-def test_hybrid_program_fits_one_v5e(shapes_of, program):
-    compiled = lowerings(*shapes_of(HYBRID), bucket=HYBRID_BUCKET)[
-        program]().compile()
+def test_hybrid_program_fits_one_v5e(compiled_of, program):
+    compiled = compiled_of(HYBRID, program)
     mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert used < V5E_HBM, (
@@ -195,13 +220,14 @@ def test_hybrid_program_fits_one_v5e(shapes_of, program):
 
 
 @pytest.mark.parametrize("program", ["decode_window_1", "decode_window_max"])
-def test_hybrid_decode_window_updates_its_state_in_place(shapes_of, program):
+def test_hybrid_decode_window_updates_its_state_in_place(shapes_of,
+                                                         compiled_of,
+                                                         program):
     """The window carries K/V and the Mamba-2 state through its scans: no
     temporary near the size of the cache plus state, and no copy of the
     whole ``mamba_h``, ``k`` or ``v`` stack."""
-    shapes = shapes_of(HYBRID)
-    cache = shapes[2]
-    compiled = lowerings(*shapes)[program]().compile()
+    cache = shapes_of(HYBRID)[2]
+    compiled = compiled_of(HYBRID, program)
     cache_bytes = sum(a.size * a.dtype.itemsize
                       for a in jax.tree.leaves(cache))
     temp = compiled.memory_analysis().temp_size_in_bytes
@@ -215,3 +241,18 @@ def test_hybrid_decode_window_updates_its_state_in_place(shapes_of, program):
         copies = re.findall(rf"= \(?\w+\[{dims}\][^=]* copy(?:-start)?\(",
                             text)
         assert not copies, f"{program}: whole-{leaf} copies {copies}"
+
+
+@pytest.mark.parametrize("arch,program", [
+    (arch, program)
+    for arch in ("granite-3-2b", "h2o-danube-1.8b")
+    for program in ("decode_window_1", "decode_window_max", "fused_window_max")
+] + [(HYBRID, "decode_window_1"), (HYBRID, "decode_window_max")])
+def test_decode_window_reads_the_cache_through_the_kernel(compiled_of, arch,
+                                                          program):
+    """The layer scan's attention is the Pallas kernel's Mosaic custom
+    call, at 512-wide rows (granite, the hybrid) and 640-wide (danube)."""
+    calls = re.findall(r"%decode_attention[.\d]* = [^\n]*"
+                       r'custom_call_target="tpu_custom_call"',
+                       compiled_of(arch, program).as_text())
+    assert calls, f"{arch} {program}: no decode attention kernel"
