@@ -1,17 +1,14 @@
 """Benchmark harness entry point: one benchmark per paper table/figure.
 
-    PYTHONPATH=src python -m benchmarks.run [--only NAME] [--skip-roofline]
+    PYTHONPATH=src python -m benchmarks.run [--only NAME]
 
 Prints ``name,us_per_call,derived`` CSV rows (scaffold contract) followed by
-the human-readable detail lines, and appends the roofline table when
-dry-run artifacts are present.
+the human-readable detail lines.
 """
 
 from __future__ import annotations
 
 import argparse
-import os
-import sys
 import time
 
 
@@ -19,7 +16,6 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="substring filter on benchmark names")
-    ap.add_argument("--skip-roofline", action="store_true")
     ap.add_argument("--replicas", default=None,
                     help="comma list for the fleet sweep, e.g. 1,2,4")
     ap.add_argument("--routers", default=None,
@@ -53,22 +49,6 @@ def main() -> None:
     print()
     for line in all_detail:
         print(line)
-
-    if not args.skip_roofline and os.path.exists("dryrun_results.jsonl"):
-        print("\n=== §Roofline (from multi-pod dry-run artifacts) ===")
-        try:
-            from benchmarks.roofline import main as roofline_main
-
-            roofline_main("dryrun_results.jsonl")
-        except Exception as e:  # noqa: BLE001
-            print(f"(roofline unavailable: {e})")
-        try:
-            from benchmarks.roofline import optimized_comparison
-
-            print("\n=== §Perf: baseline vs optimized sharding (O1-O4) ===")
-            print(optimized_comparison())
-        except Exception as e:  # noqa: BLE001
-            print(f"(optimized comparison unavailable: {e})")
 
 
 if __name__ == "__main__":

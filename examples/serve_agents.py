@@ -54,7 +54,8 @@ per-agent line reports its hit fraction.
 ``--fused-prefill`` (engine backend only) streams each admitted prompt
 into the jitted decode windows one chunk-slice per iteration instead of
 stalling every running decoder for a whole prefill pass at admission —
-the TTFT/TBT interference fix measured by ``benchmarks/perf_slo.py``.
+the TTFT/TBT interference fix ``tests/test_slo_metrics.py`` holds to its
+TTFT p99 claim.
 All flag combinations (``--stream --prefix-cache --closed-loop
 --fused-prefill``) exercise the same serving path.
 

@@ -1,48 +1,25 @@
 #!/usr/bin/env bash
-# CI entry, four stages over the unified AgentService API:
+# CI entry, three stages over the unified AgentService API:
 #
 #   1. smokes   — the serving launcher on BOTH backends, single and
 #                 multi-replica (ReplicatedBackend + router), ~40s CPU;
 #   2. tier-1   — the cross-backend event-conformance suite first (its
 #                 own named gate: the lifecycle-grammar contract every
 #                 backend must satisfy), then the default pytest tier
-#                 (slow-marked kernel/model-zoo/training sweeps are
-#                 deselected via addopts; the full tier re-runs the
-#                 conformance file — cheap, and -x keeps one red gate
-#                 from hiding behind another);
-#   3. perf     — `benchmarks/perf.py --quick` (sim core),
-#                 `benchmarks/perf_engine.py --quick` (engine hot path),
-#                 and `benchmarks/perf_cache.py --quick` (prefix-cache
-#                 fairness-vs-hit-rate): each first PROVES the optimized
-#                 core behaviour-identical to its retained pre-rewrite
-#                 oracle on seeded workloads (the cache bench proves the
-#                 cache-OFF engine bit-identical, then gates saved>0,
-#                 allocator invariants, and the locality_fair-vs-justitia
-#                 hit/delay claim in-band), plus
-#                 `benchmarks/perf_slo.py --quick` (fused-off oracle +
-#                 SLO latency), `benchmarks/perf_faults.py --quick`
-#                 (fault-off oracle, deterministic crash failover,
-#                 under-budget stall inertness, watermark swap-cut), and
-#                 `benchmarks/perf_suspend.py --quick` (suspend-off
-#                 oracle, think-time KV retention hold/spill/drop,
-#                 graceful hold->spill escalation), and
-#                 `benchmarks/perf_fleet.py --quick` (concurrent-vs-
-#                 sequential bit-identity gate, device-overlap speedup,
-#                 streaming constant-memory scale): each records its
-#                 BENCH_*_quick.json; `benchmarks/trend.py` renders
-#                 every BENCH artifact into TREND.md (all uploaded in CI);
-#                 tier-1 additionally re-runs the concurrency suites
-#                 under PYTHONDEVMODE=1 + faulthandler (thread-safety);
-#   4. slow     — `pytest -m slow`: the full kernel/model/training sweeps.
-#                 Run as its own stage so a Pallas-on-CPU container gap
-#                 cannot mask a broken scheduler/serving path.
+#                 (slow-marked model-zoo sweeps are deselected via
+#                 addopts; the full tier re-runs the conformance file —
+#                 cheap, and -x keeps one red gate from hiding behind
+#                 another); the frozen-oracle gates (optimized sim and
+#                 engine cores against their references) are tier-1
+#                 tests; tier-1 additionally re-runs the concurrency
+#                 suites under PYTHONDEVMODE=1 + faulthandler
+#                 (thread-safety);
+#   3. slow     — `pytest -m slow`: the full-model consistency and
+#                 per-architecture forward sweeps on reduced configs.
 #
-#   scripts/ci.sh            # smokes + tier-1 + perf (the gating stages)
+#   scripts/ci.sh            # smokes + tier-1 (the gating stages)
 #   scripts/ci.sh --smoke    # smokes only
-#   scripts/ci.sh --slow     # all four stages.  NB: on CPU-only
-#                            # containers the slow tier carries the known
-#                            # Pallas kernel failures, so this exits red
-#                            # there by design — it needs an accelerator.
+#   scripts/ci.sh --slow     # all three stages
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
@@ -85,33 +62,6 @@ echo "== tier-1 thread-safety: concurrency suites under PYTHONDEVMODE =="
 PYTHONDEVMODE=1 PYTHONFAULTHANDLER=1 python -m pytest -x -q \
     tests/test_fleet_concurrent.py tests/test_faults.py \
     tests/test_suspend.py
-
-echo "== perf: benchmarks/perf.py --quick (oracle + 1k sim-core bench) =="
-# separate output paths: the committed BENCH_sim.json / BENCH_engine.json
-# are the FULL-tier records (acceptance numbers) and must not be
-# overwritten by the quick stage
-python -m benchmarks.perf --quick --out BENCH_sim_quick.json
-
-echo "== perf: benchmarks/perf_engine.py --quick (engine oracle + hot-path bench) =="
-python -m benchmarks.perf_engine --quick --out BENCH_engine_quick.json
-
-echo "== perf: benchmarks/perf_cache.py --quick (cache-off oracle + prefix-cache bench) =="
-python -m benchmarks.perf_cache --quick --out BENCH_cache_quick.json
-
-echo "== perf: benchmarks/perf_slo.py --quick (fused-off oracle + SLO latency bench) =="
-python -m benchmarks.perf_slo --quick --out BENCH_slo_quick.json
-
-echo "== perf: benchmarks/perf_faults.py --quick (fault-off oracle + failover/watermark bench) =="
-python -m benchmarks.perf_faults --quick --out BENCH_faults_quick.json
-
-echo "== perf: benchmarks/perf_suspend.py --quick (suspend-off oracle + think-time retention bench) =="
-python -m benchmarks.perf_suspend --quick --out BENCH_suspend_quick.json
-
-echo "== perf: benchmarks/perf_fleet.py --quick (concurrent-fleet identity + overlap/streaming bench) =="
-python -m benchmarks.perf_fleet --quick --out BENCH_fleet_quick.json
-
-echo "== perf: benchmarks/trend.py -> TREND.md =="
-python -m benchmarks.trend --out TREND.md > /dev/null
 
 if [[ "${1:-}" == "--slow" ]]; then
     echo "== slow tier: pytest -m slow =="

@@ -18,5 +18,3 @@ CONFIG = ModelConfig(
     n_experts=16,
     top_k=4,
 )
-
-LONG_CONTEXT_OVERRIDES = {"sliding_window": 8192}

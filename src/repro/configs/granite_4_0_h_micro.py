@@ -34,5 +34,3 @@ CONFIG = ModelConfig(
     norm_eps=1e-5,
     tie_embeddings=True,
 )
-
-LONG_CONTEXT_OVERRIDES = {}  # mamba state is O(1); 4 attention layers

@@ -2,7 +2,6 @@
 attention [arXiv:2401.16818].
 
 24L d_model=2560 32H (GQA kv=8) d_ff=6912 vocab=32000, SWA window 4096.
-Native SWA: long_500k runs with the arch's own window (ring-buffer cache).
 """
 
 from repro.models.config import ModelConfig
@@ -19,5 +18,3 @@ CONFIG = ModelConfig(
     rope_theta=10_000.0,
     sliding_window=4096,
 )
-
-LONG_CONTEXT_OVERRIDES = {}  # native SWA already sub-quadratic

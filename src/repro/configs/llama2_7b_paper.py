@@ -18,5 +18,3 @@ CONFIG = ModelConfig(
     vocab=32000,
     rope_theta=10_000.0,
 )
-
-LONG_CONTEXT_OVERRIDES = {"sliding_window": 8192}

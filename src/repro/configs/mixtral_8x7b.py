@@ -20,5 +20,3 @@ CONFIG = ModelConfig(
     n_experts=8,
     top_k=2,
 )
-
-LONG_CONTEXT_OVERRIDES = {}  # native SWA

@@ -1,7 +1,6 @@
 """starcoder2-7b [dense] — GQA, RoPE [arXiv:2402.19173].
 
 32L d_model=4608 36H (GQA kv=4) d_ff=18432 vocab=49152.
-36 heads % 16 != 0 -> head_dim sharding.
 """
 
 from repro.models.config import ModelConfig
@@ -17,5 +16,3 @@ CONFIG = ModelConfig(
     vocab=49152,
     rope_theta=1_000_000.0,
 )
-
-LONG_CONTEXT_OVERRIDES = {"sliding_window": 8192}

@@ -2,8 +2,8 @@
 
 24L d_model=1024 4H d_ff=0 (no separate FFN; the xLSTM block is the mixer)
 vocab=50304.  Layers alternate mLSTM/sLSTM (slstm_every=2 -> 12 pairs).
-Recurrent state is O(1) per sequence: long_500k runs natively, and the
-paper's memory-centric cost model degenerates to linear (DESIGN.md §4).
+Recurrent state is O(1) per sequence, so the paper's memory-centric cost
+model degenerates to linear.
 """
 
 from repro.models.config import ModelConfig
@@ -19,5 +19,3 @@ CONFIG = ModelConfig(
     vocab=50304,
     slstm_every=2,   # recurrence encodes position; no pos table / rope used
 )
-
-LONG_CONTEXT_OVERRIDES = {}  # native O(1) state

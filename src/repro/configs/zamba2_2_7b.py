@@ -23,5 +23,3 @@ CONFIG = ModelConfig(
     ssm_state=64,
     attn_every=6,
 )
-
-LONG_CONTEXT_OVERRIDES = {}  # mamba state is O(1); attn KV sharded over seq

@@ -323,14 +323,13 @@ class LocalityFairScheduler(VtcScheduler):
     An over-served agent keeps its locality bonus only while within
     ``deficit_bound`` of the most-starved agent, so the max extra delay
     any agent can suffer to locality is the time to deal
-    ``deficit_bound`` service units — the bounded-pampering knob the
-    BENCH cells sweep.  The default bound is ONE pool capacity of
-    service: a multi-turn session accumulates service of the same order
-    as the pool itself, so a materially tighter bound (e.g. half a
-    pool) trips mid-session under contention and collapses the order to
-    plain fair queuing — BENCH_cache's deficit sweep shows the hit rate
-    degrading from the pure-LPM ceiling toward VTC's as the bound
-    shrinks below one pool.
+    ``deficit_bound`` service units — the bounded-pampering knob.  The
+    default bound is ONE pool capacity of service: a multi-turn session
+    accumulates service of the same order as the pool itself, so a
+    materially tighter bound (e.g. half a pool) trips mid-session under
+    contention and collapses the order to plain fair queuing — a deficit
+    sweep on the contended chat fleet showed the hit rate degrading from
+    the pure-LPM ceiling toward VTC's as the bound shrank below one pool.
 
     ``dynamic=True`` and ``agent_keyed=False`` per the OrderedQueue
     contract: the key reads the GLOBAL min counter, so one agent's

@@ -2,7 +2,7 @@
 
 ``ServeEngine`` is the device-resident hot path; ``ReferenceServeEngine``
 (``repro.engine.reference``) is the frozen pre-rewrite core kept as the
-behavioural oracle and perf baseline for ``benchmarks/perf_engine.py``.
+behavioural oracle the tests hold it to.
 """
 
 from repro.engine.engine import (

@@ -1,15 +1,13 @@
-"""Frozen pre-PR-4 serving engine: the behavioural oracle and perf baseline.
+"""Frozen pre-rewrite serving engine: the behavioural oracle.
 
 This is the ``ServeEngine`` hot path exactly as it stood before the
-device-resident rewrite (PR 4), kept verbatim so that
-
-  * ``benchmarks/perf_engine.py`` can PROVE the rewrite behaviour-
-    preserving — both engines must produce identical completion dicts and
-    swap/prefill/token counts on every seeded benchmark cell before any
-    throughput number is recorded — and measure the real speedup against
-    the very code that was replaced;
-  * regression tests (``tests/test_engine_pressure.py``) can pin the
-    optimized engine against this oracle on swap-heavy workloads.
+device-resident rewrite, kept verbatim so that tests can PROVE the
+rewrite behaviour-preserving: both engines must produce identical
+completion dicts, clocks and swap/prefill/token counts on seeded
+workloads — swap-heavy ones and round after round of drains
+(``tests/test_engine_pressure.py``), and with each feature that must be
+inert when off: fused prefill (``tests/test_fused_prefill.py``) and
+suspension retention (``tests/test_suspend.py``).
 
 Like ``repro.sim.reference``, this core is deliberately FROZEN: semantic
 changes to the engine must patch ``repro.engine.engine`` and, if they are

@@ -1,13 +1,1 @@
-"""Pallas TPU kernels for the serving hot-spots (+ jnp oracles)."""
-
-from repro.kernels.mlstm_chunk import mlstm_chunk_kernel
-from repro.kernels.ops import flash_prefill, paged_gqa_decode
-from repro.kernels.ref import flash_attention_ref, paged_attention_ref
-
-__all__ = [
-    "mlstm_chunk_kernel",
-    "flash_prefill",
-    "paged_gqa_decode",
-    "flash_attention_ref",
-    "paged_attention_ref",
-]
+"""Pallas TPU kernels for the serving hot spots."""

@@ -4,8 +4,7 @@ Tracks a fixed pool of KV blocks (block_size tokens each, in the paper's
 model-independent per-token units — footnote 1).  Sequences own block
 tables; admission, growth, swap-out and swap-in are all expressed in whole
 blocks.  The allocator is pure bookkeeping: the tensor cache lives in the
-engine; on TPU the block table is what the Pallas paged-attention kernel
-walks (kernels/paged_attention.py).
+engine, as one contiguous reservation per slot.
 """
 
 from __future__ import annotations

@@ -1,1 +1,1 @@
-"""Launchers: production mesh, sharding policy, dry-run, train/serve."""
+"""Launchers: the serving CLI and the persistent compile cache."""

@@ -1,6 +1,6 @@
 """Model zoo: configs, layers, SSM blocks, and the unified Model."""
 
-from repro.models.config import INPUT_SHAPES, Block, InputShape, ModelConfig
+from repro.models.config import ModelConfig
 from repro.models.transformer import Model
 
-__all__ = ["INPUT_SHAPES", "Block", "InputShape", "ModelConfig", "Model"]
+__all__ = ["ModelConfig", "Model"]

@@ -10,17 +10,6 @@ exact numbers from its source paper / model card.
 from __future__ import annotations
 
 import dataclasses
-import enum
-from typing import Optional
-
-
-class Block(enum.Enum):
-    """Sequence-mixing block kinds a layer stack can be built from."""
-
-    ATTN = "attn"          # (GQA) attention, optionally sliding-window
-    MLSTM = "mlstm"        # xLSTM matrix-memory block
-    SLSTM = "slstm"        # xLSTM scalar-memory block
-    MAMBA2 = "mamba2"      # Mamba2 SSD block
 
 
 @dataclasses.dataclass(frozen=True)
@@ -78,11 +67,6 @@ class ModelConfig:
     tie_embeddings: bool = False
     dtype: str = "bfloat16"
 
-    # distribution policy (resolved per-arch; see DESIGN.md §5)
-    # "heads"    -> shard attention over the head axis
-    # "head_dim" -> shard attention over the per-head feature axis
-    attn_shard: str = "auto"
-
     def __post_init__(self):
         if self.head_dim == 0:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
@@ -115,36 +99,6 @@ class ModelConfig:
     def use_rope(self) -> bool:
         return self.position == "rope"
 
-    def attn_shard_mode(self, model_par: int) -> str:
-        """Resolve 'auto' against a model-parallel degree."""
-        if self.attn_shard != "auto":
-            return self.attn_shard
-        return "heads" if self.n_heads % model_par == 0 else "head_dim"
-
-    def n_params(self) -> int:
-        """Approximate parameter count (reporting/roofline only)."""
-        d, f, v = self.d_model, self.d_ff, self.vocab
-        hd, nh, nkv = self.head_dim, self.n_heads, self.n_kv_heads
-        attn = d * hd * nh + 2 * d * hd * nkv + hd * nh * d
-        if self.is_moe:
-            ffn = self.n_experts * 3 * d * f
-        else:
-            ffn = 3 * d * f
-        per_layer = attn + ffn + 2 * d
-        total = self.n_layers * per_layer + v * d * (1 if self.tie_embeddings else 2)
-        if self.n_enc_layers:
-            total += self.n_enc_layers * (attn + ffn + 2 * d)
-        return int(total)
-
-    def n_active_params(self) -> int:
-        """Params touched per token (MoE: only top_k experts)."""
-        if not self.is_moe:
-            return self.n_params()
-        d, f = self.d_model, self.d_ff
-        dense_ffn = self.n_layers * 3 * d * f
-        total = self.n_params() - self.n_layers * self.n_experts * 3 * d * f
-        return int(total + self.top_k * dense_ffn)
-
     def reduced(self, **overrides) -> "ModelConfig":
         """Smoke-test variant: same family, tiny dims (CPU-runnable)."""
         small = dict(
@@ -176,21 +130,3 @@ class ModelConfig:
             small["n_kv_heads"] = 1
         small.update(overrides)
         return dataclasses.replace(self, **small)
-
-
-@dataclasses.dataclass(frozen=True)
-class InputShape:
-    """One of the four assigned global input shapes."""
-
-    name: str
-    seq_len: int
-    global_batch: int
-    mode: str                      # "train" | "prefill" | "decode"
-
-
-INPUT_SHAPES: dict[str, InputShape] = {
-    "train_4k": InputShape("train_4k", 4_096, 256, "train"),
-    "prefill_32k": InputShape("prefill_32k", 32_768, 32, "prefill"),
-    "decode_32k": InputShape("decode_32k", 32_768, 128, "decode"),
-    "long_500k": InputShape("long_500k", 524_288, 1, "decode"),
-}

@@ -124,11 +124,9 @@ def chunked_gqa_attention(
     """Flash-style chunked attention in pure JAX (lax.scan online softmax).
 
     Same semantics as ``gqa_attention`` but with O(S*chunk) memory instead
-    of O(S*T): mandatory for the 4k-train / 32k-prefill shapes, where the
-    full (B, H, S, T) logits tensor would not fit HBM.  On TPU the Pallas
-    ``flash_prefill`` kernel replaces this; this is the shardable jnp form
-    the dry-run lowers (XLA keeps the scan as a while loop, so HLO size and
-    live memory stay bounded).
+    of O(S*T): for long prefills, where the full (B, H, S, T) logits tensor
+    would not fit HBM.  XLA keeps the scan as a while loop, so HLO size and
+    live memory stay bounded.
     """
     b, s, nh, hd = q.shape
     t, nkv = k.shape[1], k.shape[2]

@@ -1,9 +1,9 @@
 """Logical-axis sharding annotations (MaxText-style, minimal).
 
-Model code annotates activations with *logical* axis names; the launcher
-installs a mesh and a logical->mesh rule table.  With no mesh installed
-(unit tests, CPU smoke runs) every annotation is a no-op, so the same model
-code serves single-host tests and the 512-chip dry-run.
+Model code annotates activations with *logical* axis names; a caller that
+installs a mesh and a logical->mesh rule table (``use_sharding``) turns
+them into sharding constraints.  With no mesh installed, as on every served
+path today, each annotation is a no-op.
 """
 
 from __future__ import annotations
@@ -61,12 +61,3 @@ def shard(x, *names: Optional[str]):
         )
     spec = logical_to_spec(names, rules)
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
-
-
-def param_spec(names: Sequence[Optional[str]]) -> P:
-    """PartitionSpec for a parameter (used to build in_shardings trees)."""
-    ctx = active_rules()
-    if ctx is None:
-        return P()
-    _, rules = ctx
-    return logical_to_spec(names, rules)

@@ -3,9 +3,10 @@ architecture families (dense GQA / SWA, MoE, VLM decoder, audio enc-dec,
 xLSTM, Mamba2+shared-attention hybrid, Mamba2 and attention layers at
 fixed positions with per-layer MLPs).
 
-Design choices for multi-pod dry-run sanity:
+Design choices:
   * layers are STACKED and iterated with jax.lax.scan — the HLO contains one
-    layer body regardless of depth, keeping 512-device SPMD compiles fast;
+    layer body regardless of depth, so compiles stay fast at published
+    depths;
   * caches carry an explicit per-slot position tensor ``kv_pos`` (B, T);
     full caches and SWA ring buffers share one attention masking rule
     (valid = kv_pos >= 0, causal = kv_pos <= q_pos, window optional);
@@ -13,7 +14,8 @@ Design choices for multi-pod dry-run sanity:
     (``(L, B, ...)``), recurrent state as well as K/V, so the engine's
     slot writes, gathers and swaps index ``[:, slot]`` for every kind;
   * every architecture exposes the same three entry points:
-      forward(params, batch)           -> logits            (training)
+      forward(params, batch)           -> logits   (the consistency tests'
+                                                    full-sequence pass)
       prefill(params, batch, cache_len)-> (logits, cache)   (serving)
       decode(params, cache, tokens, pos)-> (logits, cache)  (serving)
 """
@@ -30,7 +32,7 @@ import numpy as np
 
 from repro.kernels import decode_attention as decode_kernels
 from repro.models import ssm
-from repro.models.config import Block, ModelConfig
+from repro.models.config import ModelConfig
 from repro.models.layers import (
     AttnDims,
     apply_rope,
@@ -472,15 +474,11 @@ class Model:
             logits = logits / self.cfg.logits_scaling
         return shard(logits, "batch", "seq", "vocab")
 
-    # ------------------------------------------------------------ train
+    # ------------------------------------------------------------ forward
 
-    def hidden(self, params, batch: dict) -> tuple[jnp.ndarray, jnp.ndarray]:
-        """Training forward up to the FINAL NORM (no vocab projection).
-
-        Returns (normed hidden states over the token positions, moe aux).
-        The training loss projects to the vocab in chunks
-        (training.chunked_lm_loss) — materializing full (B,S,V) logits does
-        not fit HBM for the 4k/32k shapes."""
+    def forward(self, params, batch: dict) -> tuple[jnp.ndarray, jnp.ndarray]:
+        """Full-sequence forward over the token positions: (logits, moe
+        aux).  The consistency tests hold prefill and decode to it."""
         cfg = self.cfg
         tokens = batch["tokens"]
         b, s_tok = tokens.shape
@@ -492,7 +490,7 @@ class Model:
             enc = self._run_encoder(params, enc)
             positions = jnp.broadcast_to(jnp.arange(s_tok)[None], (b, s_tok))
             x = self._embed(params, tokens, positions)
-            x, aux = self._run_decoder_train(params, x, positions, enc)
+            x, aux = self._run_decoder(params, x, positions, enc)
         elif cfg.kind == "vlm" and "embeds" in batch:
             img = batch["embeds"].astype(_dtype(cfg))
             n_img = img.shape[1]
@@ -502,21 +500,15 @@ class Model:
             x_tok = jnp.take(params["embed"], tokens, axis=0)
             x = jnp.concatenate([img, x_tok], axis=1)
             x = shard(x, "batch", "seq", "embed")
-            x, aux = self._run_stack_train(params, x, positions)
+            x, aux = self._run_stack(params, x, positions)
             x = x[:, n_img:]
         else:
             positions = jnp.broadcast_to(jnp.arange(s_tok)[None], (b, s_tok))
             x = self._embed(params, tokens, positions)
-            x, aux = self._run_stack_train(params, x, positions)
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        return x, aux
+            x, aux = self._run_stack(params, x, positions)
+        return self._logits(params, x), aux
 
-    def forward(self, params, batch: dict) -> tuple[jnp.ndarray, jnp.ndarray]:
-        """Training forward returning full logits (small configs only)."""
-        x, aux = self.hidden(params, batch)
-        return self._head(params, x), aux
-
-    def _run_stack_train(self, params, x, positions, remat: bool = True):
+    def _run_stack(self, params, x, positions):
         cfg = self.cfg
         if cfg.kind in ("dense", "moe", "vlm"):
             def body(carry, lp):
@@ -524,8 +516,6 @@ class Model:
                 h, (_, _, a) = dense_block_train(lp, h, positions, cfg)
                 return (h, aux + a), None
 
-            if remat:
-                body = jax.checkpoint(body)
             (x, aux), _ = jax.lax.scan(body, (x, jnp.float32(0.0)),
                                        params["blocks"])
             return x, aux
@@ -539,8 +529,6 @@ class Model:
                 y2, _ = ssm.slstm_forward(lp["slstm"], hs)
                 return h + y2, None
 
-            if remat:
-                body = jax.checkpoint(body)
             x, _ = jax.lax.scan(body, x, params["xlstm_pairs"])
             return x, jnp.float32(0.0)
         if cfg.kind == "hybrid":
@@ -549,7 +537,6 @@ class Model:
             def body(carry, lp):
                 h = carry
 
-                @jax.checkpoint
                 def mamba_one(hc, mp_ln):
                     mp, ln = mp_ln
                     hin = rms_norm(hc, ln, cfg.norm_eps)
@@ -560,8 +547,6 @@ class Model:
                 h, _ = dense_block_train(shared, h, positions, cfg)[0], None
                 return h, None
 
-            if remat:
-                body = jax.checkpoint(body)
             x, _ = jax.lax.scan(body, x, params["super_blocks"])
             return x, jnp.float32(0.0)
         if cfg.kind == "mamba2_hybrid":
@@ -576,11 +561,11 @@ class Model:
                                     scale=cfg.qk_scale)
                 return attention_out(w, att), carry
 
-            x, _ = self._hybrid_stack(params, x, None, mixer, remat=remat)
+            x, _ = self._hybrid_stack(params, x, None, mixer)
             return x, jnp.float32(0.0)
         raise ValueError(cfg.kind)
 
-    def _hybrid_stack(self, params, x, carry, mixer, remat: bool = False):
+    def _hybrid_stack(self, params, x, carry, mixer):
         """The ``mamba2_hybrid`` layer stack: a scan over the periods of
         ``layer_types``, and inside one period a scan over each run of like
         layers, so the program holds one body per run, not one per layer.
@@ -619,8 +604,6 @@ class Model:
                                       rms_norm(x, lp["ln2"], cfg.norm_eps))
                     return (x + y * r, carry), None
 
-                if remat:
-                    layer = jax.checkpoint(layer)
                 c, _ = jax.lax.scan(layer, c, jnp.arange(n))
             return c, None
 
@@ -653,7 +636,7 @@ class Model:
         enc, _ = jax.lax.scan(body, enc, params["enc_blocks"])
         return rms_norm(enc, params["enc_final_norm"], cfg.norm_eps)
 
-    def _run_decoder_train(self, params, x, positions, enc):
+    def _run_decoder(self, params, x, positions, enc):
         cfg = self.cfg
         b, f = enc.shape[0], enc.shape[1]
         enc_pos = jnp.broadcast_to(jnp.arange(f)[None], (b, f))

@@ -2,7 +2,7 @@
 
 ``ClusterSim`` is the event-indexed production core;
 ``reference.ReferenceClusterSim`` is the retained pre-rewrite oracle the
-equivalence tests and ``benchmarks/perf.py`` pin it against.
+equivalence tests pin it against.
 """
 
 from repro.sim.cluster import ClusterSim, SimAgent, SimResult

@@ -53,8 +53,8 @@ the *exact float arithmetic* of the retained pre-rewrite core
 same stable decode form): saturation and finish events frequently land
 within 1e-10 of each other under contention, and both cores must order
 them identically or swap decisions diverge.  The equivalence property
-tests and the ``benchmarks/perf.py`` oracle pin the two cores to
-identical completion orders and JCTs.
+tests (``tests/test_sim_equivalence.py``) pin the two cores to identical
+completion orders and JCTs.
 
 The core is *incremental*: ``submit`` registers agents online at any time,
 ``advance(until)`` processes events up to a horizon (so completions are

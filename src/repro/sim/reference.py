@@ -9,10 +9,7 @@ deliberately slow and simple — as the ground truth the optimized core is
 pinned to:
 
 * ``tests/test_sim_equivalence.py`` property-checks that both cores produce
-  identical completion orders and JCTs across mixed arrival patterns;
-* ``benchmarks/perf.py`` asserts identical JCT/finish dicts on a seeded
-  1k-agent workload before recording the optimized core's throughput, and
-  reports the measured speedup against this implementation.
+  identical completion orders and JCTs across mixed arrival patterns.
 
 Semantics are identical to the optimized core by construction (one
 admission-pass structure, same event-ordering cascade arrival >

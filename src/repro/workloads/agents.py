@@ -311,7 +311,8 @@ SLO_CLASSES: tuple[str, ...] = ("interactive", "batch")
 #: per-tier latency targets (workload seconds) for the SLO closed-loop
 #: family.  Calibrated for the canonical serving configurations
 #: (sim: decode_rate=30 tok/s; engine: time_scale mapping one iteration
-#: to ``token_scale/decode_rate`` seconds — see benchmarks/perf_slo.py):
+#: to ``token_scale/decode_rate`` seconds, as tests/test_slo_metrics.py
+#: serves its contended SLO fleet):
 #: interactive agents expect a first token while a human is still
 #: watching and a readable streaming cadence; batch agents only need to
 #: start within the minute and keep moving.

@@ -191,7 +191,7 @@ def test_window_with_a_frozen_slot_serves_the_same_tokens(monkeypatch):
 
 
 def _serve(model, params, n_agents=4):
-    from benchmarks.perf_engine import synth_agents
+    from _engine_agents import synth_agents
 
     eng = ServeEngine(model, params, make_scheduler("justitia", 256.0),
                       pool_tokens=512, max_batch=4, cache_len=CACHE_LEN,

@@ -5,12 +5,15 @@ self-evicted-while-growing regression and listener event-ordering checks.
 The workloads here keep the block pool tiny so the same agents swap out
 and back in repeatedly — the regime where the rewrite's jitted slot
 gather/scatter, O(log n) victim selection, and O(1) swapped-rid membership
-all sit on the hot path.
+all sit on the hot path.  A last test serves round after round of seeded
+agents on the same two engines, under every queue policy of the oracle,
+and compares them after every drain.
 """
 
 import jax
 import numpy as np
 import pytest
+from _engine_agents import _snapshot, synth_agents
 
 from repro.configs import get_config
 from repro.core import InferenceSpec, agent_cost, make_scheduler
@@ -303,3 +306,34 @@ def test_run_until_slicing_matches_reference_with_prefill_cost(tiny_model):
         return eng.now, done
 
     assert drive(ServeEngine) == drive(ReferenceServeEngine)
+
+
+# ------------------------------------------------ oracle over drain rounds
+
+
+@pytest.mark.parametrize("sched_name", ["justitia", "vtc", "vllm-fcfs"])
+def test_engine_matches_reference_across_drain_rounds(tiny_model, sched_name):
+    """The same two engine instances serve round after round of seeded
+    agents, each round submitted after the last one drained: under a roomy
+    pool (no swaps) and a tight one (swap cycles), the optimized engine
+    must match the frozen reference's completions, clock and counters
+    after every drain, with the allocator's invariants intact."""
+    model, params = tiny_model
+    swapped = False
+    for pool in (8192, 256):
+        engines = [
+            cls(model, params, make_scheduler(sched_name, float(pool)),
+                pool_tokens=pool, max_batch=4, cache_len=96)
+            for cls in (ServeEngine, ReferenceServeEngine)
+        ]
+        for rnd in range(2):
+            for eng in engines:
+                for a in synth_agents(rnd, 6, aid0=6 * rnd):
+                    eng.submit_agent(a)
+                eng.run_until_idle(max_iters=100_000)
+                eng.alloc.check_invariants()
+            new, ref = (_snapshot(e) for e in engines)
+            assert new == ref, (pool, rnd)
+            assert len(new["completions"]) == 6 * (rnd + 1)
+        swapped |= new["swaps"] > 0
+    assert swapped, "the tight pool never swapped"

@@ -29,9 +29,9 @@ from pathlib import Path
 import jax
 import jax.numpy as jnp
 import pytest
+from _engine_agents import _snapshot, synth_agents
 
 import repro.engine.engine as engine_mod
-from benchmarks.perf_engine import _snapshot, synth_agents
 from repro.configs import get_config
 from repro.core import make_scheduler
 from repro.engine import ReferenceServeEngine, ServeEngine

@@ -8,7 +8,8 @@ difference is that each slice's children step on a thread pool and their
 buffered events are replayed in child-index order.  Work stealing must
 only ever migrate queued, never-admitted, never-suspended agents, and the
 ``least_loaded`` router must normalize its live-agent counts by replica
-capacity.
+capacity.  Streaming mode (no per-agent results kept) must hold the same
+identity while compaction keeps the tracked state bounded.
 """
 
 from __future__ import annotations
@@ -359,6 +360,98 @@ def test_streaming_mode_drops_per_agent_state():
     assert all(not c.sim._by_id for c in fleet.children)
     assert not fleet._compact_done
     fleet.close()
+
+
+def _paced_specs(seed: int, n: int, *, window: float, stages: int = 2):
+    rng = np.random.default_rng(seed)
+    return [
+        AgentSpec(
+            stages=[[InferenceSpec(int(rng.integers(120, 400)),
+                                   int(rng.integers(20, 80)))]
+                    for _ in range(stages)],
+            arrival=float(rng.uniform(0.0, window)),
+        )
+        for _ in range(n)
+    ]
+
+
+def test_heterogeneous_fleet_wide_replicas_serve_more():
+    """A 2:1 mixed-capacity fleet under ``least_loaded`` with stealing
+    armed: the wide replicas complete strictly more agents than the narrow
+    ones, and the concurrent run matches the sequential one, reconciled
+    virtual times included."""
+    runs = []
+    for workers in (None, 4):
+        children = [SimBackend("justitia", total_kv=m)
+                    for m in (2400.0, 2400.0, 1200.0, 1200.0)]
+        fleet = ReplicatedBackend(
+            children, router="least_loaded", seed=7, fleet_workers=workers,
+            steal_threshold=1.3, steal_interval=0.5,
+        )
+        for aid, spec in enumerate(_paced_specs(7, 48, window=10.0)):
+            fleet.submit(spec, aid)
+        fleet.run(200.0)
+        res = fleet.drain()
+        fleet.close()
+        runs.append((res.finish, res.jct, res.metrics))
+    (fin_a, jct_a, met_a), (fin_b, jct_b, met_b) = runs
+    assert fin_a == fin_b and jct_a == jct_b
+    assert met_a["virtual_times"] == met_b["virtual_times"]
+    served = [row["agents"] for row in met_b["per_replica"]]
+    assert served[0] + served[1] > served[2] + served[3]
+
+
+def _streaming_run(workers, n: int = 2000, seed: int = 7):
+    """Pace ``n`` one-stage agents through a streaming fleet (children
+    keep no results, the fleet keeps no agents) at 60% of its capacity,
+    compacting behind a 10 s horizon after every batch."""
+    children = [SimBackend("justitia", total_kv=1200.0,
+                           retain_results=False) for _ in range(4)]
+    fleet = ReplicatedBackend(
+        children, router="round_robin", seed=seed, fleet_workers=workers,
+        steal_threshold=1.3, steal_interval=0.5, retain_agents=False,
+    )
+    tape = RawTape()
+    fleet.set_listener(tape)
+    rng = np.random.default_rng(seed)
+    specs = [AgentSpec(stages=[[InferenceSpec(int(rng.integers(80, 240)),
+                                              int(rng.integers(10, 40)))]])
+             for _ in range(n)]
+    mean_cost = np.mean([s.resolved_costs()[0] for s in specs])
+    rate = 0.6 * sum(fleet.virtual_capacities) / mean_cost
+    peak = 0
+    for lo in range(0, n, 500):
+        for aid in range(lo, lo + 500):
+            specs[aid].arrival = aid / rate
+            fleet.submit(specs[aid], aid)
+        peak = max(peak, len(fleet._specs))
+        fleet.run((lo + 500) / rate)
+        fleet.compact(max(0.0, (lo + 500) / rate - 10.0))
+    t = n / rate
+    while sum(c.in_flight for c in children) > 0:
+        t += 5.0
+        fleet.run(t)
+    fleet.drain()
+    snap = fleet.compact(fleet.now)
+    residual = (fleet._specs, fleet.assignment,
+                fleet.global_clock.virtual_finish, fleet._compact_done,
+                *(c.sim._by_id for c in children))
+    assert not any(residual), "per-agent state left after the last compact"
+    steals = len(fleet._steals)
+    fleet.close()
+    return tape.events, snap.virtual_times, steals, peak
+
+
+def test_streaming_fleet_concurrent_matches_sequential():
+    """Streaming mode with stealing armed: the concurrent fleet emits the
+    sequential fleet's exact event stream and reconciled clock, completes
+    every agent, and tracks a bounded window of agents, not all of them."""
+    seq = _streaming_run(None)
+    con = _streaming_run(4)
+    assert con[:3] == seq[:3]
+    done = [e for e in seq[0] if e[0] == "on_agent_complete"]
+    assert len(done) == 2000
+    assert max(seq[3], con[3]) < 1000
 
 
 # ------------------------------------------------------- engine backend

@@ -28,10 +28,10 @@ here:
 import jax
 import numpy as np
 import pytest
+from _engine_agents import _snapshot, synth_agents
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
-from benchmarks.perf_engine import _snapshot, synth_agents
 from repro.configs import get_config
 from repro.core import InferenceSpec, agent_cost, make_scheduler
 from repro.engine import EngineAgent, ReferenceServeEngine, ServeEngine
@@ -268,3 +268,4 @@ def test_fused_windows_are_scheduling_free(
             f"fused stream overshot the prompt: wrote {written} of "
             f"{total} (chunk {chunk})"
         )
+

@@ -318,18 +318,3 @@ def test_chunked_attention_equals_full():
                                    chunk_q=16, chunk_k=16)
         np.testing.assert_allclose(np.asarray(full), np.asarray(ch),
                                    atol=1e-5, rtol=1e-4)
-
-
-@slow
-def test_chunked_lm_loss_equals_plain():
-    from repro.training import chunked_lm_loss, lm_loss
-
-    key = jax.random.PRNGKey(0)
-    b, s, d, v = 2, 24, 16, 64
-    x = jax.random.normal(key, (b, s, d))
-    head = jax.random.normal(jax.random.PRNGKey(1), (d, v)) * 0.1
-    toks = jax.random.randint(jax.random.PRNGKey(2), (b, s), 0, v)
-    logits = jnp.einsum("bsd,dv->bsv", x, head)
-    plain = lm_loss(logits, toks)
-    chunked = chunked_lm_loss(x, head, toks, chunk=8)
-    np.testing.assert_allclose(float(plain), float(chunked), rtol=1e-5)

@@ -1,6 +1,7 @@
 """Prefix-aware KV reuse subsystem (PR 6): refcount/COW invariants on
-``PrefixAwareAllocator``, eviction safety, and the sim-vs-engine
-hit-fraction correspondence through the AgentService facade."""
+``PrefixAwareAllocator``, eviction safety, the sim-vs-engine hit-fraction
+correspondence through the AgentService facade, and ``locality_fair``'s
+hit rate against ``justitia`` on a contended chat fleet."""
 
 import jax
 import numpy as np
@@ -15,6 +16,7 @@ from repro.api import (
     EngineBackend,
     PrefixHit,
     SimBackend,
+    specs_from_closed_loop,
 )
 from repro.configs import get_config
 from repro.core import InferenceSpec
@@ -311,3 +313,41 @@ def test_prefix_hit_events_and_hooks(tiny_model):
     assert {ev.agent_id for ev in seen} == {0, 1}
     for ev in seen:
         assert 0 < ev.cached <= ev.prefill
+
+
+# ------------------------------------------ locality against fair queuing
+
+
+def _chat_fleet_run(model, params, sched: str):
+    """A closed-loop chat fleet of 32 sessions on a pool of about 1.5
+    scaled prompts per 4 slots: the free list stays exhausted, so idle
+    session chains face LRU eviction between turns and admission order
+    moves the hit rate."""
+    svc = AgentService.engine(
+        model, params, sched, pool_tokens=384, max_batch=4, cache_len=512,
+        prefill_chunk=32, token_scale=8, time_scale=1.0, seed=0,
+        prefix_cache=True, record_events=False,
+    )
+    specs = specs_from_closed_loop(np.random.default_rng(7), 32, 30.0,
+                                   classes=("chat",))
+    svc.submit_many(specs)
+    res = svc.drain()
+    eng = svc.backend.engine
+    eng.alloc.check_invariants()
+    saved = res.metrics["prefill_tokens_saved"]
+    assert saved > 0, f"{sched}: the cache served nothing"
+    return saved / sum(eng.agent_prefill_tokens.values()), max(
+        res.jct.values())
+
+
+def test_locality_fair_beats_justitia_hit_rate_within_delay_bound(
+    tiny_model,
+):
+    """Selective pampering is fair but cache-oblivious: on the contended
+    chat fleet, ``locality_fair`` must serve more prefill from cache than
+    ``justitia`` while its worst JCT stays within 1.15x of justitia's."""
+    model, params = tiny_model
+    hit_j, max_j = _chat_fleet_run(model, params, "justitia")
+    hit_l, max_l = _chat_fleet_run(model, params, "locality_fair")
+    assert hit_l > hit_j
+    assert max_l <= 1.15 * max_j

@@ -1,4 +1,4 @@
-"""Equivalence and perf-harness tests for the event-indexed simulator core.
+"""Equivalence tests for the event-indexed simulator core.
 
 The optimized ``repro.sim.ClusterSim`` must be *behaviour-preserving*
 against the retained pre-rewrite core
@@ -7,11 +7,9 @@ orders, JCTs (within 1e-6; in practice the two cores are float-identical
 by construction — see the stable decode form in both modules), swap and
 event counts — across schedulers, pool sizes, and mixed arrival patterns.
 Also covers the shared ``OrderedQueue``, the virtual-work GPS rewrite, the
-admission-overshoot guard, incremental ``advance`` vs batch drain, the
-load-aware router fix, and the CI perf-stage smoke.
+admission-overshoot guard, incremental ``advance`` vs batch drain, and the
+load-aware router fix.
 """
-
-import json
 
 import numpy as np
 import pytest
@@ -270,6 +268,29 @@ def test_closed_loop_stage_append_identical_across_cores():
     # every agent really served the appended second stage
     stages = [e for e in la.stream if e[0] == "stage"]
     assert sorted(e[1] for e in stages if e[2] == 1) == list(range(6))
+
+
+def test_token_events_leave_closed_loop_jcts_identical():
+    """Through the service facade, 300 closed-loop sessions (multi-turn
+    chat and react loops whose stages are generated mid-run) served with
+    token streaming off and on: the overlay must leave every finish time
+    and JCT bit-identical."""
+    from repro.api import AgentService, specs_from_closed_loop
+
+    runs = []
+    for stream in (False, True):
+        specs = specs_from_closed_loop(np.random.default_rng(0), 300, 120.0)
+        svc = AgentService.sim(
+            "justitia", total_kv=16384.0, decode_rate=DECODE_RATE,
+            record_events=False, token_events=stream,
+        )
+        svc.submit_many(specs)
+        res = svc.drain()
+        assert len(res.finish) == 300
+        runs.append(res)
+    off, on = runs
+    assert on.event_counts.get("TokenGenerated", 0) > 0
+    assert on.finish == off.finish and on.jct == off.jct
 
 
 # ------------------------------------------------------------------- GPS
@@ -644,27 +665,3 @@ def test_least_loaded_router_sees_sim_completions_mid_run():
     assert backend.assignment[3] in (0, 1)  # tie after re-balancing
     res = svc.drain()
     assert len(res.finish) == 4
-
-
-# ------------------------------------------------------------ perf stage
-
-
-def test_quick_perf_bench_completes_under_ceiling(tmp_path):
-    """CI perf-stage smoke: the 1k-agent quick benchmark (oracle check +
-    sweep) finishes well under a generous wall-clock ceiling and records a
-    passing oracle."""
-    import time
-
-    from benchmarks.perf import main as perf_main
-
-    out = tmp_path / "BENCH_sim.json"
-    t0 = time.time()
-    result = perf_main(["--quick", "--out", str(out)])
-    wall = time.time() - t0
-    assert wall < 240.0, f"quick perf bench took {wall:.0f}s"
-    assert out.exists()
-    data = json.loads(out.read_text())
-    assert data["oracle"]["match"] is True
-    assert data["oracle"]["max_abs_diff"] < 1e-6
-    assert result["optimized"] and result["reference"]
-    assert all(r["events_per_s"] > 0 for r in data["optimized"])

@@ -20,12 +20,18 @@ before running ones.  Checked here:
   * the Equinox question: ``think_time_accrual=False`` removes thinking
     agents from the fleet GPS reference via buffered suspend/resume
     notes; both stances serve the workload to completion;
-  * the real engine serves the same grammar (hold and drop).
+  * the real engine serves the same grammar (hold and drop);
+  * with no think time every retention is inert: the sim serves the same
+    run under each, and the engine matches the frozen reference engine;
+  * under think time, ``drop`` evicts less than ``hold``, ``hold``
+    escalates instead of wedging (sim fleet and real engine), and a
+    think-time fleet replays bit-identically.
 """
 
 import jax
 import numpy as np
 import pytest
+from _engine_agents import _snapshot, synth_agents
 from hypothesis import given, settings
 from hypothesis import strategies as st
 from test_event_conformance import assert_conformant_stream
@@ -40,11 +46,14 @@ from repro.api import (
     FaultPlan,
     SimBackend,
     StageCompleted,
+    specs_from_closed_loop,
 )
 from repro.configs import get_config
-from repro.core import InferenceSpec
+from repro.core import InferenceSpec, agent_cost, make_scheduler
 from repro.core.virtual_time import GlobalVirtualClock
+from repro.engine import ReferenceServeEngine, ServeEngine
 from repro.models import Model
+from repro.sim import ClusterSim, SimAgent
 
 RETENTIONS = ("hold", "spill", "drop")
 
@@ -409,3 +418,128 @@ def test_engine_suspension_conformance(tiny_model, retention):
         susp = next(e for e in h.events if isinstance(e, AgentSuspended))
         resm = next(e for e in h.events if isinstance(e, AgentResumed))
         assert resm.time >= susp.until - 1e-9
+
+
+# ------------------------------------------- retention without think time
+
+
+def _static_sim_agents():
+    """40 seeded one- and two-stage agents with no closed-loop session:
+    nothing ever suspends."""
+    rng = np.random.default_rng(11)
+    out = []
+    for i in range(40):
+        stages = [
+            [InferenceSpec(int(rng.integers(50, 400)),
+                           int(rng.integers(10, 120)))]
+            for _ in range(int(rng.integers(1, 3)))
+        ]
+        cost = agent_cost([s for st in stages for s in st])
+        out.append(SimAgent(agent_id=i, arrival=float(rng.uniform(0, 20)),
+                            stages=stages, predicted_cost=cost,
+                            true_cost=cost))
+    return out
+
+
+@pytest.mark.parametrize("sched", ["justitia", "vtc", "vllm-fcfs"])
+def test_sim_retention_inert_without_think_time(sched):
+    """Suspension is strictly delay-gated: with no resume delay, every
+    retention setting serves the workload bit-identically."""
+    runs = {}
+    for retention in RETENTIONS:
+        res = ClusterSim(make_scheduler(sched, 1500.0, service_rate=30.0),
+                         1500.0, suspend_retention=retention,
+                         ).run(_static_sim_agents())
+        assert res.suspensions == 0
+        runs[retention] = (res.finish, res.jct, res.swaps, res.events)
+    assert runs["spill"] == runs["hold"] and runs["drop"] == runs["hold"]
+
+
+def test_engine_retention_inert_without_think_time(tiny_model):
+    """The engine side of the same rule: with nothing suspending, every
+    retention setting reproduces the frozen reference engine."""
+    model, params = tiny_model
+    for sched in ("justitia", "vtc"):
+        kw = dict(pool_tokens=256, max_batch=4, cache_len=96)
+        ref = ReferenceServeEngine(model, params,
+                                   make_scheduler(sched, 256.0), **kw)
+        for a in synth_agents(3, 10):
+            ref.submit_agent(a)
+        ref.run_until_idle(max_iters=100_000)
+        for retention in RETENTIONS:
+            eng = ServeEngine(model, params, make_scheduler(sched, 256.0),
+                              suspend_retention=retention, **kw)
+            for a in synth_agents(3, 10):
+                eng.submit_agent(a)
+            eng.run_until_idle(max_iters=100_000)
+            eng.alloc.check_invariants()
+            assert eng.metrics["suspensions"] == 0
+            assert _snapshot(eng) == _snapshot(ref), (sched, retention)
+
+
+# ------------------------------------------- retention under think time
+
+
+def _think_fleet(retention: str):
+    """A contended think-time fleet: 12 ``tooluse`` sessions on two sim
+    replicas with the prefix cache on."""
+    svc = AgentService.sim(
+        "justitia", replicas=2, total_kv=1500.0, record_events=False,
+        prefix_cache=True, suspend_retention=retention,
+    )
+    svc.submit_many(specs_from_closed_loop(
+        np.random.default_rng(7), 12, 6.0, classes=("tooluse",)))
+    return svc.drain()
+
+
+def test_drop_evicts_less_than_hold_within_jct_spread():
+    """Held think-time KV is the pressure source: ``drop`` must evict
+    strictly less than ``hold`` (swap-outs plus hold->spill escalations,
+    which pay the same restore), ``hold`` must escalate rather than wedge,
+    and the worst JCT may differ across retentions by at most 3x."""
+    runs = {r: _think_fleet(r) for r in RETENTIONS}
+    for retention, res in runs.items():
+        assert len(res.finish) == 12, retention
+        assert res.metrics["suspensions"] >= 1, retention
+        assert res.metrics["suspensions"] == res.metrics["resumes"]
+    evictions = {r: res.swaps + res.metrics["suspend_spills"]
+                 for r, res in runs.items()}
+    assert evictions["drop"] < evictions["hold"]
+    assert runs["hold"].metrics["suspend_spills"] >= 1
+    worst = [max(res.jct.values()) for res in runs.values()]
+    assert max(worst) <= 3.0 * min(worst)
+
+
+def test_think_fleet_replays_bit_identical():
+    a, b = _think_fleet("hold"), _think_fleet("hold")
+    assert a.finish == b.finish and a.jct == b.jct
+    for key in ("suspensions", "resumes", "suspend_spills", "held_peak"):
+        assert a.metrics[key] == b.metrics[key], key
+
+
+def test_engine_hold_escalates_pinned_kv_under_tight_pool(tiny_model):
+    """On the real engine with a pool too tight for six thinking agents'
+    held KV, ``hold`` escalates pinned KV instead of stalling, and both
+    ``hold`` and ``drop`` serve every turn."""
+    model, params = tiny_model
+    spills = {}
+    for retention in ("hold", "drop"):
+        svc = AgentService.engine(
+            model, params, "justitia", pool_tokens=96, max_batch=2,
+            cache_len=96, token_scale=1, time_scale=1.0,
+            record_events=False, prefix_cache=True,
+            suspend_retention=retention,
+        )
+        for i in range(6):
+            svc.submit(AgentSpec(
+                stages=[[InferenceSpec(40, 12)]], arrival=0.2 * i,
+                next_stage=ScriptedSession([[InferenceSpec(40, 12)]] * 3,
+                                           [3.0] * 3),
+                predicted_cost=200.0, true_cost=200.0,
+            ))
+        res = svc.drain()
+        assert len(res.finish) == 6, retention
+        assert res.metrics["suspensions"] == res.metrics["resumes"] == 18
+        spills[retention] = res.metrics["suspend_spills"]
+    assert spills["hold"] >= 1
+    assert spills["drop"] == 0
